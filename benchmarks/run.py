@@ -27,6 +27,8 @@ import os
 import sys
 import time
 
+from repro.matching import enable_persistent_compile_cache
+
 from . import (autotune, batch_matching, corpus, fig2_bfs_iters,
                fig35_speedups, perf_matcher, perf_smoke, roofline, serving,
                sharded_matching, table1_variants, table2_hardest, table_init,
@@ -207,6 +209,7 @@ def main() -> None:
     ap.add_argument("--runs", type=int, default=3,
                     help="runs folded into the --update-baseline envelope")
     args = ap.parse_args()
+    enable_persistent_compile_cache()
     if args.list:
         for name, fn in BENCHES.items():
             doc = (fn.__module__.replace("benchmarks.", "")

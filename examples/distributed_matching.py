@@ -2,7 +2,8 @@
 
 ``ShardedMatcher`` — edge-partitioned APFB over a device mesh, one ``pmin``
 collective per BFS level, same solve loop as the single-device ``Matcher``
-(see docs/architecture.md).  Runs on 8 simulated host devices:
+(see docs/architecture.md).  Runs on every device JAX sees: the chips of a
+TPU host, or 8 simulated devices on a CPU-only machine:
 
     PYTHONPATH=src python examples/distributed_matching.py
 """
@@ -19,12 +20,12 @@ from repro.matching import (DeviceCSR, Matcher, MatcherConfig,             # noq
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((jax.device_count(),), ("data",))
     g = random_bipartite(4096, 4096, 6.0, seed=0)
     graph = DeviceCSR.from_host(g).shard(mesh, "data")
     print(f"graph: {g.nc}x{g.nr}, {g.nnz} edges, "
-          f"sharded over {mesh.shape['data']} devices "
-          f"({graph.nnz_pad // 8} edges/device)")
+          f"sharded over {mesh.size} devices "
+          f"({graph.nnz_pad // mesh.size} edges/device)")
     cfg = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
     sharded = ShardedMatcher(mesh, config=cfg, warm_start="cheap")
     state = sharded.run(graph)            # warm start + solve, one program

@@ -8,10 +8,12 @@ import numpy as np
 from repro.core import hopcroft_karp, validate_matching
 from repro.graphs import kron_graph, random_bipartite
 from repro.matching import (DeviceCSR, Matcher, MatcherConfig, VARIANTS,
-                            compile_cache_info, match_many)
+                            compile_cache_info,
+                            enable_persistent_compile_cache, match_many)
 
 
 def main():
+    enable_persistent_compile_cache()
     # a power-law bipartite graph (kron_g500-style, as in the paper's suite)
     g = kron_graph(scale=12, edge_factor=8, seed=1)
     print(f"graph: {g.nc} cols, {g.nr} rows, {g.nnz} edges")

@@ -116,24 +116,35 @@ class BipartiteCSR:
 
 def validate_matching(g: BipartiteCSR, cmatch: np.ndarray, rmatch: np.ndarray) -> int:
     """Check matching validity; return its cardinality. Raises on violation."""
-    cmatch = np.asarray(cmatch)[: g.nc]
-    rmatch = np.asarray(rmatch)[: g.nr]
-    edge_set = set(zip(g.ecol[: g.nnz].tolist(), g.cadj[: g.nnz].tolist()))
-    card = 0
-    for c in range(g.nc):
-        r = int(cmatch[c])
-        if r == UNMATCHED:
-            continue
-        assert 0 <= r < g.nr, f"cmatch[{c}]={r} out of range"
-        assert int(rmatch[r]) == c, f"asymmetric match c={c} r={r} rmatch[r]={rmatch[r]}"
-        assert (c, r) in edge_set, f"matched non-edge ({c},{r})"
-        card += 1
-    for r in range(g.nr):
-        c = int(rmatch[r])
-        if c == UNMATCHED:
-            continue
-        assert 0 <= c < g.nc and int(cmatch[c]) == r, f"asymmetric match r={r} c={c}"
-    return card
+    cmatch = np.asarray(cmatch)[: g.nc].astype(np.int64)
+    rmatch = np.asarray(rmatch)[: g.nr].astype(np.int64)
+    cols = np.flatnonzero(cmatch != UNMATCHED)
+    rows = cmatch[cols]
+    in_range = (rows >= 0) & (rows < g.nr)
+    safe = np.where(in_range, rows, 0)
+    symmetric = in_range & (rmatch[safe] == cols)
+    # (c, r) is an edge iff its key is among the sorted real-edge keys; the
+    # int64-max tail keeps every searchsorted position a valid index
+    keys = np.append(np.sort(g.ecol[: g.nnz].astype(np.int64) * g.nr
+                             + g.cadj[: g.nnz]), np.iinfo(np.int64).max)
+    want = cols * g.nr + safe
+    is_edge = symmetric & (keys[np.searchsorted(keys, want)] == want)
+    if not is_edge.all():
+        i = int(np.argmin(is_edge))                  # first offending column
+        c, r = int(cols[i]), int(rows[i])
+        assert in_range[i], f"cmatch[{c}]={r} out of range"
+        assert symmetric[i], \
+            f"asymmetric match c={c} r={r} rmatch[r]={rmatch[r]}"
+        raise AssertionError(f"matched non-edge ({c},{r})")
+    mrows = np.flatnonzero(rmatch != UNMATCHED)
+    mcols = rmatch[mrows]
+    ok = (mcols >= 0) & (mcols < g.nc)
+    ok &= cmatch[np.where(ok, mcols, 0)] == mrows
+    if not ok.all():
+        i = int(np.argmin(ok))
+        raise AssertionError(
+            f"asymmetric match r={int(mrows[i])} c={int(mcols[i])}")
+    return len(cols)
 
 
 def is_maximal(g: BipartiteCSR, cmatch: np.ndarray, rmatch: np.ndarray

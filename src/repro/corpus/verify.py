@@ -38,7 +38,8 @@ import numpy as np
 from repro.core.csr import BipartiteCSR, is_maximal, validate_matching
 from repro.core.oracles import hopcroft_karp
 from repro.graphs import instance_sets, mtx_fixture
-from repro.matching import SOLVE_PATHS, MatcherConfig
+from repro.matching import (SOLVE_PATHS, MatcherConfig,
+                            enable_persistent_compile_cache)
 from repro.matching.device_csr import bucket_nnz
 
 ARTIFACT_SCHEMA = "repro-corpus-failure/1"
@@ -277,6 +278,7 @@ def main(argv=None) -> int:
                     help="phase budget for the base config (0 = unlimited); "
                          "implies degrade_maximal when --oracle maximal")
     args = ap.parse_args(argv)
+    enable_persistent_compile_cache()
     base = MatcherConfig()
     if args.max_phases:
         base = dataclasses.replace(

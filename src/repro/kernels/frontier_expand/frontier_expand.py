@@ -1,4 +1,7 @@
-"""Pallas TPU kernels: edge-tiled BFS frontier expansion (paper Alg. 2/4).
+"""Pallas kernels: edge-tiled BFS frontier expansion (paper Alg. 2/4).
+
+Written for the TPU, run in interpret mode: the TPU compiler rejects them
+(see "What the TPU compiler does with them" below).
 
 TPU adaptation of the paper's GPUBFS / GPUBFS-WR CUDA kernels
 --------------------------------------------------------------
@@ -13,8 +16,8 @@ On TPU the analogous structure is:
   is what the GPU coalesced accesses become;
 * the BFS state vectors (``bfs``, ``root``, ``rmatch``) stay VMEM-resident
   across the whole grid (they are O(n) and reused by every tile) and are
-  accessed with on-chip dynamic gathers — the GPU's random global-memory
-  reads become VMEM gathers with ~20x the bandwidth;
+  read with dynamic gathers by edge endpoint, where the GPU reads global
+  memory at random — the gather the TPU compiler rejects (below);
 * the paper's MT/CT knob becomes ``block_edges`` (tile granularity): CT's
   coarse-grained strided batches correspond to large tiles (4096 lanes),
   MT's fine-grained one-vertex-per-thread to small tiles (512).
@@ -33,15 +36,9 @@ Three kernel families share one proposal formula (:func:`_proposals`):
   bit-identical to ``scatter_min`` of the legacy proposals (min is the merge
   in both, so tile order cannot matter).
 
-  The tradeoff moved, it did not vanish: a data-dependent scatter still
-  does not vectorize lane-parallel on the VPU, but the fused kernel pays it
-  against VMEM instead of paying an (nnz,) HBM write + a second O(nnz) XLA
-  scatter pass over HBM — per level the streamed traffic drops from ~3·nnz
-  int32 plus the merge pass to 2·nnz in, (nr+1) out.  Compiled-TPU lowering
-  of the in-kernel scatter is exercised by the compiled-parity tests
-  (tests/test_frontier_paths.py), which run on accelerator hosts only; if
-  Mosaic ever regresses on this shape the loud failure is there, and
-  ``MatcherConfig(pallas_fused=False)`` restores the two-step path.
+  By design the streamed traffic per level drops from ~3·nnz int32 plus
+  the merge pass to 2·nnz in, (nr+1) out, at the price of a data-dependent
+  scatter into VMEM; no compiled kernel has measured that trade.
 * :func:`frontier_expand_pull` (``_kernel_pull`` / ``_kernel_pull_wr``) is
   the direction-optimizing *pull* sweep: the same accumulator contract as
   the fused family, but streaming the **CSC mirror** (``radj``/``erow``, the
@@ -61,17 +58,22 @@ the edge arrays up to the next tile multiple with inert sentinel edges
 ``cadj = nr`` lands in the winner slot that is reset to IINF), replacing the
 old hard ``nnz % block_edges == 0`` requirement.
 
-``interpret=None`` auto-detects: compile for real on accelerator backends,
-fall back to the Pallas interpreter only where there is no Mosaic/Triton
-compiler (CPU).
+What the TPU compiler does with them
+------------------------------------
+Nothing here compiles for a TPU.  Lowering any of the six kernels (three
+families x WR/plain) for a v5e fails in Mosaic with :data:`MOSAIC_REJECTION`:
+:func:`_proposals` gathers from the VMEM-resident O(n) state vectors by edge
+endpoint (``jnp.take``), a 1-D vector gather Mosaic does not lower, and the
+fused and pull merges would next need a data-dependent scatter
+(``.at[rows].min``) into the O(n) winner vector.  ``tests/test_tpu_compile.py``
+compiles them for a described v5e and asserts the rejection, so the guard in
+``MatcherConfig.canonical`` — compiled Pallas is refused when a ``Matcher`` is
+built — comes out when a kernel first compiles.  The path that runs on the
+chip is the XLA sweep (``MatcherConfig(use_pallas=False)``, the default).
+The kernels run in interpret mode on the CPU, where they are held
+bit-identical to that sweep (``tests/test_frontier_paths.py``).
 
-VMEM budget (fused, WR, defaults): 3 state vectors of (nc+1) int32 + the
-(nr+1) winner accumulator + 2 edge tiles of ``block_edges`` int32 =
-4*(3*nc + nr + 2*4096) bytes ~= 16n B + 32 KiB for square graphs; n = 800k
-fits the 16 MiB v5e VMEM.  Larger graphs partition the edges over the mesh
-(repro.matching.ShardedMatcher) and each shard tiles its own slice.  (This
-budget math is also walked through in docs/architecture.md, "The Pallas
-frontier kernel".)
+``interpret=None`` auto-detects: interpret on the CPU, compile elsewhere.
 """
 from __future__ import annotations
 
@@ -86,9 +88,13 @@ UNVISITED = 1          # python ints: safe to close over in kernels
 IINF = 2**30
 LANE = 128             # TPU lane width; the floor for any edge tile
 
+# Mosaic's error for every kernel family here, lowered for a TPU v5e under
+# JAX 0.9 / libtpu 0.0.34 (see the module docstring).
+MOSAIC_REJECTION = "NotImplementedError: Only 2D gather is supported"
+
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``None`` = auto: interpret only where Pallas cannot compile (CPU)."""
+    """``None`` = auto: interpret on the CPU, compile anywhere else."""
     if interpret is None:
         return jax.default_backend() == "cpu"
     return bool(interpret)
@@ -324,36 +330,14 @@ def frontier_expand_fused(ecol, cadj, bfs, root, rmatch, level, *,
     Returns the ``(nr+1,)`` int32 winner vector (lowest proposing column per
     row, IINF = unreached; slot ``nr`` is the IINF sentinel) — bit-identical
     to ``scatter_min`` over :func:`frontier_expand` proposals, with no
-    (nnz,) intermediate.
-
-    The carried accumulator relies on the grid executing *sequentially*
-    (TPU, and the interpreter); on a parallel-grid backend (GPU/Triton) the
-    read-modify-write across blocks would race, so there the same contract
-    is kept by composing the legacy proposal kernel with an XLA min-scatter.
+    (nnz,) intermediate.  The carried accumulator relies on the grid
+    executing *sequentially*, as the TPU's and the interpreter's do.
     """
     check_edge_geometry(int(ecol.shape[0]), block_edges)
-    interp = resolve_interpret(interpret)
-    if not interp and jax.default_backend() != "tpu":
-        return _winner_via_legacy(ecol, cadj, bfs, root, rmatch, level,
-                                  block_edges=block_edges)
     return _sweep_impl(ecol, cadj, bfs, root, rmatch, level,
-                       block_edges=block_edges, interpret=interp,
+                       block_edges=block_edges,
+                       interpret=resolve_interpret(interpret),
                        family="fused")
-
-
-def _winner_via_legacy(ecol, cadj, bfs, root, rmatch, level, *,
-                       block_edges: int):
-    """Parallel-grid (GPU/Triton) fallback keeping the winner contract:
-    legacy proposal kernel composed with an XLA min-scatter — the carried
-    accumulator needs a sequential grid, which only TPU (and the
-    interpreter) guarantee."""
-    nr = rmatch.shape[0] - 1
-    prop = _sweep_impl(ecol, cadj, bfs, root, rmatch, level,
-                       block_edges=block_edges, interpret=False,
-                       family="legacy")
-    rows = jnp.where(prop < IINF, cadj, jnp.int32(nr))
-    win = jnp.full(nr + 1, IINF, jnp.int32).at[rows].min(prop)
-    return win.at[nr].set(jnp.int32(IINF))
 
 
 def frontier_expand_pull(radj, erow, bfs, root, rmatch, level, *,
@@ -367,17 +351,11 @@ def frontier_expand_pull(radj, erow, bfs, root, rmatch, level, *,
     :func:`frontier_expand_fused` — the proposal predicate is per-edge and
     min is the merge, so edge order cannot change the winners — but tiles
     whose row range no longer contains unreached rows skip their in-VMEM
-    scatter entirely (see ``_merge_tile_pull``).
-
-    Like the fused family, the carried accumulator needs a sequential grid;
-    on non-TPU compiled backends the contract is kept by the legacy
-    proposal kernel + XLA min-scatter over the same (permuted) edge arrays.
+    scatter entirely (see ``_merge_tile_pull``).  Like the fused family,
+    the carried accumulator needs a sequential grid.
     """
     check_edge_geometry(int(radj.shape[0]), block_edges)
-    interp = resolve_interpret(interpret)
-    if not interp and jax.default_backend() != "tpu":
-        return _winner_via_legacy(radj, erow, bfs, root, rmatch, level,
-                                  block_edges=block_edges)
     return _sweep_impl(radj, erow, bfs, root, rmatch, level,
-                       block_edges=block_edges, interpret=interp,
+                       block_edges=block_edges,
+                       interpret=resolve_interpret(interpret),
                        family="pull")

@@ -32,7 +32,8 @@ import numpy as np
 from repro.core.csr import BipartiteCSR
 from repro.graphs import (grid_graph, kron_graph, random_bipartite,
                           scaled_free)
-from repro.matching import DeviceCSR, Matcher, MatcherConfig
+from repro.matching import (DeviceCSR, Matcher, MatcherConfig,
+                            enable_persistent_compile_cache)
 from repro.serving import (Bucketizer, FaultInjector, FlushThreadDiedError,
                            MatchingService, PoisonedGraphFault, SizeBucket,
                            ladder, percentile)
@@ -134,6 +135,7 @@ def main(argv=None) -> int:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="FaultInjector seed (deterministic fault schedule)")
     args = ap.parse_args(argv)
+    enable_persistent_compile_cache()
 
     if args.smoke:
         args.requests, args.rate, args.size = 12, 500.0, 224

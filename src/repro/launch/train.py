@@ -18,7 +18,7 @@ import time
 
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.ckpt import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import ARCH_NAMES, get_config
@@ -38,8 +38,11 @@ def shardings_for(mesh, specs_tree, value_tree):
 def run(arch: str, steps: int, smoke: bool, mesh_shape, batch: int,
         seq: int, ckpt_dir: str, simulate_failure: int = 0,
         microbatch: int = 0, log_every: int = 10, lr: float = 3e-4):
+    # Auto axes: the model's sharding constraints name mesh axes, which
+    # jax.make_mesh's default Explicit axes refuse
     mesh = jax.make_mesh(mesh_shape, ("data", "model")[: len(mesh_shape)]
-                         if len(mesh_shape) > 1 else ("data",))
+                         if len(mesh_shape) > 1 else ("data",),
+                         axis_types=(AxisType.Auto,) * len(mesh_shape))
     logical = {"data": ("data",), "model": ("model",)
                if "model" in mesh.axis_names else ()}
     if "model" not in mesh.shape:

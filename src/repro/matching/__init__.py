@@ -17,14 +17,15 @@ The public surface of the reproduction:
   ``pmin`` collective per BFS level (the paper's stated future work);
 * an explicit compile cache keyed on (bucket shape, config, warm start, and
   for the sharded path mesh/axis), replacing the scattered per-module
-  ``functools.lru_cache`` jits.
+  ``functools.lru_cache`` jits, and :func:`enable_persistent_compile_cache`,
+  which entry points call to keep compiled programs on disk across runs.
 
 ``repro.core.maximum_matching`` / ``cheap_matching_jax`` /
 ``repro.core.distributed`` remain as thin numpy-compat wrappers over this
 package.  ``docs/architecture.md`` documents the design; ``docs/paper_map.md``
 maps every paper algorithm to its implementation here.
 """
-from .config import MatcherConfig, VARIANTS
+from .config import MatcherConfig, PallasUnsupportedError, VARIANTS
 from .device_csr import DeviceCSR, GraphValidationError, validate_structure
 from .state import MatchState, MatchStats
 from .warmstart import WARM_STARTS, register_warm_start, warm_start_names
@@ -33,10 +34,11 @@ from .sharded import ShardedMatcher, match_sharded, mesh_cache_key
 from .paths import (SOLVE_PATHS, SolvePath, register_solve_path,
                     solve_path_names, unregister_solve_path)
 from .cache import (compile_cache_clear, compile_cache_info,
-                    compile_cache_key, get_compiled)
+                    compile_cache_key, enable_persistent_compile_cache,
+                    get_compiled)
 
 __all__ = [
-    "MatcherConfig", "VARIANTS",
+    "MatcherConfig", "PallasUnsupportedError", "VARIANTS",
     "DeviceCSR", "GraphValidationError", "validate_structure",
     "MatchState", "MatchStats",
     "Matcher", "match_many", "maximum_matching_device",
@@ -45,5 +47,5 @@ __all__ = [
     "solve_path_names", "unregister_solve_path",
     "WARM_STARTS", "register_warm_start", "warm_start_names",
     "compile_cache_clear", "compile_cache_info", "compile_cache_key",
-    "get_compiled",
+    "enable_persistent_compile_cache", "get_compiled",
 ]

@@ -35,7 +35,8 @@ class Matcher:
                  warm_start: str = "none"):
         # canonical(): the pallas_interpret=None auto marker resolves to the
         # backend's concrete compilation mode here, so every compile-cache
-        # key built from self.config carries the real interpret bool.
+        # key built from self.config carries the real interpret bool; it
+        # also refuses compiled Pallas kernels here, before any tracing.
         self.config = config.canonical()
         self.warm_start = warm_start
         get_warm_start(warm_start)      # fail fast on unknown names
@@ -98,6 +99,29 @@ class Matcher:
         return (self.warm_start, warm_start_version(self.warm_start))
 
     # -- compiled entry points ------------------------------------------------
+    def program(self, graph: DeviceCSR, cold: bool = True):
+        """The jitted ``(graph, state) -> state`` program that :meth:`run`
+        (or :meth:`run_many`, for a stacked graph) dispatches for
+        ``graph``'s bucket; ``cold`` fuses the warm start in front of the
+        solve.  ``graph`` may hold ``jax.ShapeDtypeStruct`` leaves, so
+        ``program(g).lower(g, state).compile()`` compiles ahead of time.
+        """
+        batched = bool(graph.batch_shape)
+        key = compile_cache_key(graph.bucket_key, self.config,
+                                self._cache_tag(cold),
+                                "run_many" if batched else "run")
+
+        def build():
+            one = self.solve
+            if cold:
+                # _init_pure, not init: going through the public entry inside
+                # this build would register a second ("init") cache entry at
+                # trace time (AOT warmup counts on one program per entry).
+                one = lambda g, s: self.solve(g, self._init_pure(g, s))  # noqa: E731
+            return jax.vmap(one) if batched else one
+
+        return get_compiled(key, build)
+
     def run(self, graph: DeviceCSR, state: Optional[MatchState] = None
             ) -> MatchState:
         """Maximum matching on device.
@@ -113,18 +137,7 @@ class Matcher:
         cold = state is None
         if cold:
             state = empty_like_graph(graph)
-        ws = self._cache_tag(cold)
-        key = compile_cache_key(graph.bucket_key, self.config, ws, "run")
-
-        def build():
-            if cold:
-                # _init_pure, not init: going through the public entry inside
-                # this build would register a second ("init") cache entry at
-                # trace time (AOT warmup counts on one program per entry).
-                return lambda g, s: self.solve(g, self._init_pure(g, s))
-            return self.solve
-
-        return get_compiled(key, build)(graph, state)
+        return self.program(graph, cold)(graph, state)
 
     def run_many(self, graphs: DeviceCSR,
                  states: Optional[MatchState] = None) -> MatchState:
@@ -147,18 +160,7 @@ class Matcher:
         cold = states is None
         if cold:
             states = empty_like_graph(graphs)
-        ws = self._cache_tag(cold)
-        key = compile_cache_key(graphs.bucket_key, self.config, ws,
-                                "run_many")
-
-        def build():
-            if cold:
-                one = lambda g, s: self.solve(g, self._init_pure(g, s))  # noqa: E731
-            else:
-                one = self.solve
-            return jax.vmap(one)
-
-        return get_compiled(key, build)(graphs, states)
+        return self.program(graphs, cold)(graphs, states)
 
     def stats(self, state: MatchState) -> MatchStats:
         """Device-scalar stats labelled with this matcher's variant name."""

@@ -13,9 +13,14 @@ and whatever thread calls ``submit``.  Capacity is ``MAX_ENTRIES``,
 overridable with :func:`set_max_entries`; evictions are counted and exposed
 in :func:`compile_cache_info` so a serving deployment can see when its
 declared warmup grid no longer fits the cache.
+
+That table lives as long as the process.  :func:`enable_persistent_compile_cache`
+adds JAX's on-disk cache under it, so a new process finds the executables an
+earlier one compiled; entry points call it first thing in ``main``.
 """
 from __future__ import annotations
 
+import os
 import threading
 from typing import Callable, Dict, Hashable, Tuple
 
@@ -29,6 +34,27 @@ _MISSES = 0
 _EVICTIONS = 0
 _LOCK = threading.RLock()
 _TLS = threading.local()      # per-thread hit/miss tallies (see below)
+
+
+def enable_persistent_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is set here.  Otherwise the cache is ``.jax_cache`` at the root
+    of the checkout this file belongs to — a fixed path, because the path
+    is part of what a later run must find again.  Call it before the first
+    compile; importing the package never calls it, so tests write no cache.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    from jax.experimental.compilation_cache import compilation_cache
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    path = os.path.join(root, ".jax_cache")
+    compilation_cache.set_cache_dir(path)
+    compilation_cache.reset_cache()   # a compile before this call fixed "none"
+    return path
 
 
 def _thread_counts() -> dict:

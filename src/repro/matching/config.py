@@ -5,6 +5,16 @@ import dataclasses
 from typing import Optional
 
 
+class PallasUnsupportedError(NotImplementedError):
+    """``use_pallas=True`` asked for compiled Pallas kernels on a backend
+    that cannot compile them (every compiled backend, today: the TPU
+    compiler rejects the frontier kernels, see
+    :data:`repro.kernels.frontier_expand.frontier_expand.MOSAIC_REJECTION`).
+    Raised when a :class:`~repro.matching.Matcher` is built, so the refusal
+    never surfaces inside a traced program and never falls back to the
+    interpreter."""
+
+
 @dataclasses.dataclass(frozen=True)
 class MatcherConfig:
     """One of the paper's eight variants (2 algos x 2 BFS kernels x 2
@@ -23,7 +33,9 @@ class MatcherConfig:
     kernel: str = "gpubfs_wr"   # "gpubfs" | "gpubfs_wr"
     schedule: str = "ct"        # "ct" | "mt" — edge-tile geometry (Pallas path)
     wr_exact: bool = False      # the APsB-GPUBFS-WR refinement (negative-row encoding)
-    use_pallas: bool = False    # route frontier expansion through the Pallas kernel
+    # route frontier expansion through the Pallas kernels: interpret mode
+    # only (the CPU test path); compiled, they are refused by canonical()
+    use_pallas: bool = False
     max_phases: int = 0         # 0 = until maximum (bounded internally)
     # When a positive max_phases budget exhausts before the solver certifies
     # the matching maximum, run one extra greedy augmentation round
@@ -45,9 +57,10 @@ class MatcherConfig:
     # False = legacy two-step path (proposal kernel + XLA scatter), kept for
     # benchmarking the fusion win (benchmarks/perf_smoke.py).
     pallas_fused: bool = True
-    # None = auto: compile for real on accelerator backends, interpret only
-    # on CPU.  Resolved once per Matcher (``canonical()``) so the concrete
-    # bool — not the auto marker — lands in the compile-cache key.
+    # None = auto: interpret on CPU, compile elsewhere (which canonical()
+    # refuses while use_pallas is on).  Resolved once per Matcher
+    # (``canonical()``) so the concrete bool — not the auto marker — lands
+    # in the compile-cache key.
     pallas_interpret: Optional[bool] = None
     # 0 = auto (default_block_edges: CT 4096 / MT 512, clamped to the padded
     # edge count); >0 = explicit tile size, e.g. from benchmarks/autotune.py.
@@ -136,12 +149,26 @@ class MatcherConfig:
     def canonical(self) -> "MatcherConfig":
         """Resolve the ``pallas_interpret=None`` auto marker to a concrete
         bool (interpret only on CPU) so compile-cache keys built from this
-        config always carry the real compilation mode."""
-        if self.pallas_interpret is not None:
-            return self
-        from repro.kernels.frontier_expand import resolve_interpret
-        return dataclasses.replace(self,
-                                   pallas_interpret=resolve_interpret(None))
+        config always carry the real compilation mode.
+
+        Raises :class:`PallasUnsupportedError` for ``use_pallas=True`` with
+        interpretation off: no installed compiler accepts the kernels, so the
+        solve path on an accelerator is the XLA sweep (``use_pallas=False``).
+        """
+        from repro.kernels.frontier_expand.frontier_expand import (
+            MOSAIC_REJECTION, resolve_interpret)
+        cfg = self
+        if cfg.pallas_interpret is None:
+            cfg = dataclasses.replace(
+                cfg, pallas_interpret=resolve_interpret(None))
+        if cfg.use_pallas and not cfg.pallas_interpret:
+            raise PallasUnsupportedError(
+                "MatcherConfig(use_pallas=True) needs the Pallas interpreter "
+                "(pallas_interpret=True, the CPU test path): the TPU "
+                f"compiler rejects the frontier kernels ({MOSAIC_REJECTION}"
+                " — they gather from O(n) VMEM vectors by edge endpoint). "
+                "Use use_pallas=False, the XLA sweep, on an accelerator.")
+        return cfg
 
 
 VARIANTS = tuple(

@@ -124,6 +124,19 @@ def bucket_nnz(nnz: int, lane: int = LANE) -> int:
     return cap
 
 
+def auto_mesh(mesh):
+    """``mesh`` with every axis ``Auto``-typed (same devices, same names).
+
+    ``jax.make_mesh`` types its axes ``Explicit`` by default.  The sharded
+    matcher leaves the warm start outside ``shard_map`` for GSPMD to
+    partition, which only ``Auto`` axes allow, so :meth:`DeviceCSR.shard`
+    and :class:`~repro.matching.ShardedMatcher` place and compile on this
+    view of whatever mesh the caller built.
+    """
+    from jax.sharding import AxisType
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def per_shard_nnz(nnz_pad: int, ndev: int, lane: int = LANE) -> int:
     """Per-device edge capacity when sharding ``nnz_pad`` edges over ``ndev``
     devices: each shard is itself a canonical bucket.  Shared by
@@ -338,9 +351,14 @@ class DeviceCSR:
         they accumulate at the tail, but the per-level sweep is a dense
         vector op over every lane of a shard, so work per device is exactly
         the shard capacity no matter how the real edges distribute.
+
+        Arrays are placed on the :func:`auto_mesh` view of ``mesh``, so a
+        graph already placed on the caller's ``Explicit`` mesh is re-placed,
+        not passed through.
         """
         assert not self.batch_shape, "shard() takes a single graph"
         from jax.sharding import NamedSharding, PartitionSpec as P
+        mesh = auto_mesh(mesh)
         ndev = int(mesh.shape[axis])
         per_shard = per_shard_nnz(self.nnz_pad, ndev)
         g = self if ndev * per_shard == self.nnz_pad \

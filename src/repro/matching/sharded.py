@@ -29,9 +29,12 @@ all-reduce moves ``2*(D-1)/D * 4*(nr+1)`` bytes per link
 The warm start runs *outside* the ``shard_map`` region, as plain jnp inside
 the same jitted program: GSPMD partitions its scatter/gather rounds over the
 sharded edge arrays automatically, so every registry entry
-(``none``/``cheap``/``karp_sipser``/custom) works unmodified.  Compiled
-programs live in the shared compile cache, keyed additionally on the mesh
-fingerprint and axis name.
+(``none``/``cheap``/``karp_sipser``/custom) works unmodified.  GSPMD may only
+do that over ``Auto`` mesh axes, while ``jax.make_mesh`` types its axes
+``Explicit`` by default; the matcher therefore places and compiles on the
+:func:`~repro.matching.device_csr.auto_mesh` view of the caller's mesh.
+Compiled programs live in the shared compile cache, keyed additionally on the
+mesh fingerprint and axis name.
 """
 from __future__ import annotations
 
@@ -40,11 +43,10 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map_no_check
 from .api import Matcher
 from .cache import compile_cache_key, get_compiled
 from .config import MatcherConfig
-from .device_csr import DeviceCSR
+from .device_csr import DeviceCSR, auto_mesh
 from .solve import make_solver
 from .state import MatchState, MatchStats, empty_like_graph
 from .warmstart import get_warm_start
@@ -87,7 +89,7 @@ class ShardedMatcher(Matcher):
                 "(use MatcherConfig(dirop=True) for a direction heuristic "
                 "that composes with sharding)")
         assert axis in mesh.axis_names, (axis, mesh.axis_names)
-        self.mesh = mesh
+        self.mesh = auto_mesh(mesh)
         self.axis = axis
 
     def run(self, graph: DeviceCSR, state: Optional[MatchState] = None
@@ -110,9 +112,14 @@ class ShardedMatcher(Matcher):
         cold = state is None
         if cold:
             state = empty_like_graph(graph)
-        ws = self._cache_tag(cold)
+        return self.program(graph, cold)(graph, state)
+
+    def program(self, graph: DeviceCSR, cold: bool = True):
+        """The jitted program :meth:`run` dispatches for ``graph``'s bucket
+        on this mesh (see :meth:`Matcher.program`); ``graph`` is taken as
+        already edge-sharded, as :meth:`run` leaves it."""
         key = compile_cache_key(
-            graph.bucket_key, self.config, ws,
+            graph.bucket_key, self.config, self._cache_tag(cold),
             ("sharded_run",) + mesh_cache_key(self.mesh, self.axis))
         dirop = self.config.dirop
 
@@ -124,9 +131,11 @@ class ShardedMatcher(Matcher):
             in_specs = (P(self.axis), P(self.axis), P(), P())
             if dirop:
                 in_specs += (P(), P(), P(self.axis), P(self.axis))
-            smap = shard_map_no_check(
-                solve, self.mesh, in_specs=in_specs,
-                out_specs=(P(), P(), P(), P(), P()))
+            # check_vma off: the level loop is a while_loop over replicated
+            # state that the varying-manual-axes check cannot follow
+            smap = jax.shard_map(
+                solve, mesh=self.mesh, in_specs=in_specs,
+                out_specs=(P(), P(), P(), P(), P()), check_vma=False)
             init = get_warm_start(self.warm_start)
             cfg = self.config
 
@@ -156,7 +165,7 @@ class ShardedMatcher(Matcher):
 
             return fn
 
-        return get_compiled(key, build)(graph, state)
+        return get_compiled(key, build)
 
     def run_many(self, graphs, states=None):
         raise NotImplementedError(

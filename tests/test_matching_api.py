@@ -166,3 +166,130 @@ def test_stacked_state_shapes(graph):
     st = empty_like_graph(batch)
     assert st.cmatch.shape == (2, graph.nc + 1)
     assert st.phases.shape == (2,)
+
+
+# ---------------------------------------------------------------------------
+# bring-up guards: meshes, compiled Pallas, the persistent cache, the checker
+# ---------------------------------------------------------------------------
+def test_sharded_matcher_accepts_the_default_make_mesh(g):
+    """jax.make_mesh types its axes Explicit; the matcher works on an Auto
+    view of it, and a graph placed on the caller's mesh is re-placed."""
+    import dataclasses
+
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+    from repro.matching import ShardedMatcher
+    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    assert set(mesh.axis_types) == {AxisType.Explicit}
+    graph = DeviceCSR.from_host(g).shard(mesh, "data")
+    edges = NamedSharding(mesh, P("data"))
+    placed = dataclasses.replace(graph,
+                                 ecol=jax.device_put(graph.ecol, edges),
+                                 cadj=jax.device_put(graph.cadj, edges))
+    assert placed.ecol.sharding.mesh.axis_types == mesh.axis_types
+    assert set(placed.shard(mesh, "data").ecol.sharding.mesh.axis_types) \
+        == {AxisType.Auto}
+    state = ShardedMatcher(mesh, warm_start="cheap").run(placed)
+    assert validate_matching(g, *state.to_host()) == maximum_cardinality(g)
+
+
+def test_compiled_pallas_is_refused_when_the_matcher_is_built(monkeypatch):
+    import re
+
+    from repro.kernels.frontier_expand.frontier_expand import MOSAIC_REJECTION
+    from repro.matching import SOLVE_PATHS, PallasUnsupportedError
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pallas = [p for p in SOLVE_PATHS.values() if p.overrides.get("use_pallas")]
+    assert pallas
+    for path in pallas:
+        with pytest.raises(PallasUnsupportedError,
+                           match=re.escape(MOSAIC_REJECTION)):
+            path.matcher()
+    # the XLA sweep builds compiled; an explicitly interpreted kernel builds
+    assert Matcher(MatcherConfig()).config.pallas_interpret is False
+    assert Matcher(MatcherConfig(use_pallas=True,
+                                 pallas_interpret=True)).config.use_pallas
+
+
+def test_persistent_compile_cache_location(monkeypatch, tmp_path):
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.matching import enable_persistent_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_persistent_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before   # JAX reads it
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        path = enable_persistent_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
+def _validate_matching_loop(g, cmatch, rmatch):
+    """The straightforward per-vertex checker ``validate_matching`` replaced
+    with array operations: the reference it must agree with."""
+    cmatch, rmatch = np.asarray(cmatch)[: g.nc], np.asarray(rmatch)[: g.nr]
+    edges = set(zip(g.ecol[: g.nnz].tolist(), g.cadj[: g.nnz].tolist()))
+    card = 0
+    for c in range(g.nc):
+        r = int(cmatch[c])
+        if r == -1:
+            continue
+        assert 0 <= r < g.nr, f"cmatch[{c}]={r} out of range"
+        assert int(rmatch[r]) == c, f"asymmetric match c={c} r={r}"
+        assert (c, r) in edges, f"matched non-edge ({c},{r})"
+        card += 1
+    for r in range(g.nr):
+        c = int(rmatch[r])
+        if c != -1:
+            assert 0 <= c < g.nc and int(cmatch[c]) == r, \
+                f"asymmetric match r={r} c={c}"
+    return card
+
+
+@pytest.mark.parametrize("corrupt,message", [
+    ("row_out_of_range", "out of range"),
+    ("asymmetric", "asymmetric match c="),
+    ("non_edge", "matched non-edge"),
+    ("row_side_only", "asymmetric match r="),
+])
+def test_validate_matching_rejects_each_violation(corrupt, message):
+    from repro.core.oracles import hopcroft_karp
+    g = random_bipartite(120, 110, 2.0, seed=11)
+    cm, rm = hopcroft_karp(g)
+    assert validate_matching(g, cm, rm) == maximum_cardinality(g) \
+        == _validate_matching_loop(g, cm, rm)
+    cm, rm = cm.copy(), rm.copy()
+    matched = np.flatnonzero(cm >= 0)
+    free_c, free_r = np.flatnonzero(cm < 0), np.flatnonzero(rm < 0)
+    edges = set(zip(g.ecol[: g.nnz].tolist(), g.cadj[: g.nnz].tolist()))
+    if corrupt == "row_out_of_range":
+        cm[matched[0]] = g.nr + 3
+    elif corrupt == "asymmetric":
+        cm[matched[0]] = cm[matched[1]]
+    elif corrupt == "non_edge":
+        c, r = next((int(c), int(r)) for c in free_c for r in free_r
+                    if (c, r) not in edges)
+        cm[c], rm[r] = r, c
+    else:
+        rm[free_r[0]] = matched[0]
+    for check in (validate_matching, _validate_matching_loop):
+        with pytest.raises(AssertionError, match=message):
+            check(g, cm, rm)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_validate_matching_agrees_with_the_loop_reference(seed):
+    """Cheap (maximal, not maximum) matchings on rectangular graphs, with
+    free vertices on both sides: the same cardinality from both checkers."""
+    from repro.core.oracles import cheap_matching
+    g = random_bipartite(150 + 10 * seed, 130, 1.5 + seed, seed=seed)
+    cm, rm = cheap_matching(g)
+    assert validate_matching(g, cm, rm) == _validate_matching_loop(g, cm, rm)
