@@ -98,6 +98,14 @@ def check_matching(g, cm, rm, opt: int, what: str) -> int:
     return card
 
 
+def check_levels(state, what: str) -> int:
+    """The solver's BFS level count: at least one level per phase."""
+    levels, phases = int(state.levels), int(state.phases)
+    if levels < phases:
+        raise AssertionError(f"{what}: levels={levels} < phases={phases}")
+    return levels
+
+
 def check_config(matcher) -> None:
     """The chip runs the XLA sweep, compiled: never the Pallas
     interpreter (the CPU rehearsal is the one place it is on)."""
@@ -127,10 +135,12 @@ def phase_single() -> None:
         graph = DeviceCSR.from_host(g)
         state, timing = timed_run(lambda: matcher.run(graph))
         card = check_matching(g, *state.to_host(), oracle[name], name)
+        levels = check_levels(state, name)
         log(f"[single] {name} {g.nc}x{g.nr} nnz={g.nnz} "
             f"{matcher.config.name}+karp_sipser pallas_interpret="
             f"{matcher.config.pallas_interpret} {timing} "
-            f"phases={int(state.phases)} |M|={card} oracle={oracle[name]}")
+            f"phases={int(state.phases)} levels={levels} |M|={card} "
+            f"oracle={oracle[name]}")
 
 
 def phase_batch() -> None:
@@ -251,7 +261,8 @@ def phase_sharded(n_devices: int) -> None:
     ref, timing = timed_run(lambda: single.run(single_graph), steady=False)
     ref_card = check_matching(g, *ref.to_host(), opt, "single-chip")
     log(f"[sharded] single-chip device 0 warm_start={ref_ws} {timing} "
-        f"phases={int(ref.phases)} |M|={ref_card} oracle={opt}")
+        f"phases={int(ref.phases)} levels={check_levels(ref, 'single-chip')} "
+        f"|M|={ref_card} oracle={opt}")
     for ws in (ref_ws, "cheap", "none"):
         sm = ShardedMatcher(mesh, config=MatcherConfig(), warm_start=ws)
         check_config(sm)
@@ -262,7 +273,8 @@ def phase_sharded(n_devices: int) -> None:
             same = (" identical_to_single_chip=" + str(np.array_equal(
                 np.asarray(ref.cmatch), np.asarray(st.cmatch))))
         log(f"[sharded] {g.nc}x{g.nr} nnz={g.nnz} over {n_devices} devices "
-            f"warm_start={ws} {timing} phases={int(st.phases)} |M|={card} "
+            f"warm_start={ws} {timing} phases={int(st.phases)} "
+            f"levels={check_levels(st, f'sharded {ws}')} |M|={card} "
             f"single_chip={ref_card} oracle={opt}{same}")
 
 

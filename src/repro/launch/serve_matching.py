@@ -37,6 +37,7 @@ from repro.matching import (DeviceCSR, Matcher, MatcherConfig,
 from repro.serving import (Bucketizer, FaultInjector, FlushThreadDiedError,
                            MatchingService, PoisonedGraphFault, SizeBucket,
                            ladder, percentile)
+from repro.serving.metrics import STAGE_COUNTERS
 
 FAMILIES: Dict[str, Callable[[int, int], BipartiteCSR]] = {
     # name -> (size hint n, seed) -> instance
@@ -160,9 +161,11 @@ def main(argv=None) -> int:
     print(f"[serve_matching] {report}")
 
     trace = build_trace(args.requests, args.size, args.seed)
+    before, t_start = service.metrics.snapshot(), time.perf_counter()
     futures = replay(service, trace, args.rate, args.seed)
     results = [(fam, g, fut.result(timeout=300)) for fam, g, fut in futures]
     service.drain()
+    window = (service.metrics.snapshot(), time.perf_counter() - t_start)
 
     failures = 0
     per_family: Dict[str, List[float]] = {}
@@ -208,6 +211,22 @@ def main(argv=None) -> int:
     print(f"[serve_matching] latency p50 {snap['latency_p50_ms']:.1f} ms, "
           f"p99 {snap['latency_p99_ms']:.1f} ms; queue wait p50 "
           f"{snap['queue_wait_p50_ms']:.1f} ms")
+    flushes = max(1, snap["batch_flushes"])
+    print(f"[serve_matching] host per request: admit "
+          f"{snap['admit_s'] / max(1, snap['submitted']) * 1e3:.3f} ms; per "
+          f"batched flush: stack {snap['stack_s'] / flushes * 1e3:.3f} ms, "
+          f"solve {snap['batch_solve_s'] / flushes * 1e3:.3f} ms, resolve "
+          f"{snap['resolve_s'] / flushes * 1e3:.3f} ms over "
+          f"{snap['batch_flushes']} flushes; lane level share "
+          f"{100 * snap['lane_levels'] / max(1, snap['lane_level_slots']):.1f}"
+          "%")
+    # the flush thread's time over the replay, set-up left out
+    after, span = window
+    shares = {stage: 100 * (after[key] - before[key]) / span
+              for stage, key in STAGE_COUNTERS.items() if stage != "admit"}
+    print(f"[serve_matching] flush thread over the {span:.3f} s replay: "
+          + ", ".join(f"{k} {v:.1f}%" for k, v in shares.items())
+          + f"; covered {sum(shares.values()):.1f}%")
     if args.smoke:
         assert snap["dispatches"] <= snap["submitted"], \
             "batched path must not dispatch more than once per request"

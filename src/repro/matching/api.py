@@ -67,8 +67,9 @@ class Matcher:
 
     def _init_pure(self, graph: DeviceCSR, state: MatchState) -> MatchState:
         self._check_state(graph, state)
-        cm, rm = get_warm_start(self.warm_start)(
-            graph.ecol, graph.cadj, state.cmatch, state.rmatch)
+        with jax.named_scope("warm_start"):
+            cm, rm = get_warm_start(self.warm_start)(
+                graph.ecol, graph.cadj, state.cmatch, state.rmatch)
         return dataclasses.replace(state, cmatch=cm, rmatch=rm)
 
     def solve(self, graph: DeviceCSR, state: MatchState) -> MatchState:
@@ -84,12 +85,12 @@ class Matcher:
                     "it once with graph.with_csc() (serving admission does "
                     "this automatically for dirop configs)")
             kw.update(rxadj=graph.rxadj, radj=graph.radj, erow=graph.erow)
-        cm, rm, phases, fb, cert = make_solver(self.config)(
+        cm, rm, phases, fb, cert, levels = make_solver(self.config)(
             graph.ecol, graph.cadj, state.cmatch, state.rmatch, **kw)
         return MatchState(cmatch=cm, rmatch=rm,
                           phases=state.phases + phases,
                           fallbacks=state.fallbacks + fb,
-                          certified=cert)
+                          certified=cert, levels=state.levels + levels)
 
     def _cache_tag(self, cold: bool):
         """Warm-start identity for the compile cache; versioned so that

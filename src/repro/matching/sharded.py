@@ -135,7 +135,7 @@ class ShardedMatcher(Matcher):
             # state that the varying-manual-axes check cannot follow
             smap = jax.shard_map(
                 solve, mesh=self.mesh, in_specs=in_specs,
-                out_specs=(P(), P(), P(), P(), P()), check_vma=False)
+                out_specs=(P(),) * 6, check_vma=False)
             init = get_warm_start(self.warm_start)
             cfg = self.config
 
@@ -143,10 +143,11 @@ class ShardedMatcher(Matcher):
                 self._check_state(g, s)
                 cm, rm = s.cmatch, s.rmatch
                 if cold:
-                    cm, rm = init(g.ecol, g.cadj, cm, rm)
+                    with jax.named_scope("warm_start"):
+                        cm, rm = init(g.ecol, g.cadj, cm, rm)
                 extra = ((g.cxadj, g.rxadj, g.radj, g.erow) if dirop else ())
-                cm, rm, phases, fb, cert = smap(g.ecol, g.cadj, cm, rm,
-                                                *extra)
+                cm, rm, phases, fb, cert, levels = smap(g.ecol, g.cadj, cm,
+                                                        rm, *extra)
                 if cfg.degrade_maximal and cfg.max_phases > 0:
                     # Same budget-exhausted maximality repair as the
                     # single-device solver, applied OUTSIDE the shard_map
@@ -161,7 +162,7 @@ class ShardedMatcher(Matcher):
                 return MatchState(cmatch=cm, rmatch=rm,
                                   phases=s.phases + phases,
                                   fallbacks=s.fallbacks + fb,
-                                  certified=cert)
+                                  certified=cert, levels=s.levels + levels)
 
             return fn
 

@@ -482,7 +482,7 @@ def _cardinality(cmatch):
 # ---------------------------------------------------------------------------
 def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
     """Build the pure matcher ``(ecol, cadj, cmatch, rmatch[, cxadj]) ->
-    (cmatch, rmatch, phases, fallbacks, certified)``.
+    (cmatch, rmatch, phases, fallbacks, certified, levels)``.
 
     ``certified`` is a device bool: True iff the final phase's BFS proved no
     augmenting path remains (the matching is maximum, Berge).  A run cut
@@ -491,6 +491,14 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
     ``cfg.degrade_maximal`` it is additionally made maximal by one greedy
     augmentation round (single-device path; :class:`~repro.matching.sharded.
     ShardedMatcher` applies the same round outside the ``shard_map`` region).
+    ``levels`` counts the BFS levels expanded over all phases: under
+    ``vmap`` each lane counts only the levels its own loop predicate
+    allowed, and inside ``shard_map`` it is the replicated loop count.
+
+    The program carries ``jax.named_scope`` names for the trace: ``phase``
+    (one outer iteration), ``bfs_level`` (one level's sweep), ``alternate``
+    and ``fix_matching``.  They are metadata only; the last component of
+    every ``op_name`` stays the JAX primitive.
 
     Shape-polymorphic: ``nc``/``nr``/``block_edges`` are derived from the
     argument shapes at trace time, so one returned function serves every size
@@ -559,6 +567,7 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
                     go = go & (level <= aug_lvl + cfg.tail_levels)
                 return go
 
+            @jax.named_scope("bfs_level")
             def body(c):
                 bfs, root, pred, rmatch, level, _, aug, aug_lvl, dirp = c
                 if cfg.dirop:
@@ -587,10 +596,10 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
                 return (bfs, root, pred, rmatch, level + 1, ins, aug | aug_l,
                         aug_lvl, dirp)
 
-            bfs, root, pred, rmatch, _, _, aug, _, _ = jax.lax.while_loop(
+            bfs, root, pred, rmatch, level, _, aug, _, _ = jax.lax.while_loop(
                 cond, body, (bfs, root, pred, rmatch, L0, jnp.bool_(True),
                              jnp.bool_(False), IINF, jnp.bool_(False)))
-            return bfs, root, pred, rmatch, aug
+            return bfs, root, pred, rmatch, aug, level - L0    # levels run
 
         def start_mask_fn(bfs, root, rmatch):
             mask = rmatch == -2
@@ -606,18 +615,25 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
 
         max_steps = jnp.int32(2 * (min(nc, nr) + 2))
 
+        def alternate(cmatch, rmatch, pred, mask):
+            """ALTERNATE from ``mask``'s endpoints, then FIXMATCHING."""
+            with jax.named_scope("alternate"):
+                cmatch, rmatch, _ = _alternate(cmatch, rmatch, pred, mask,
+                                               max_steps)
+            with jax.named_scope("fix_matching"):
+                return _fix_matching(cmatch, rmatch)
+
+        @jax.named_scope("phase")
         def outer_body(carry):
-            cmatch, rmatch, _, phases, fallbacks = carry
+            cmatch, rmatch, _, phases, fallbacks, levels = carry
             cm0, rm0 = cmatch, rmatch                            # phase snapshot
             card0 = _cardinality(cm0)
-            bfs, root, pred, rmatch_b, aug = phase_bfs(cmatch, rmatch)
+            bfs, root, pred, rmatch_b, aug, nlev = phase_bfs(cmatch, rmatch)
 
             def do_phase(_):
                 mask = start_mask_fn(bfs, root, rmatch_b)
-                cm1, rm1, _ = _alternate(cm0,
-                                         jnp.where(mask, jnp.int32(-2), rm0),
-                                         pred, mask, max_steps)
-                cm1, rm1 = _fix_matching(cm1, rm1)
+                cm1, rm1 = alternate(cm0, jnp.where(mask, jnp.int32(-2), rm0),
+                                     pred, mask)
 
                 def fallback(_):
                     # guard: speculative phase gained nothing -> augment exactly one
@@ -625,8 +641,7 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
                     any_ep = rmatch_b == -2
                     first = jnp.argmax(any_ep)                   # lowest endpoint row
                     one = jnp.zeros(nr + 1, bool).at[first].set(jnp.any(any_ep))
-                    cm2, rm2, _ = _alternate(cm0, rm0, pred, one, max_steps)
-                    return _fix_matching(cm2, rm2) + (jnp.int32(1),)
+                    return alternate(cm0, rm0, pred, one) + (jnp.int32(1),)
 
                 cm1, rm1, fb = jax.lax.cond(
                     _cardinality(cm1) > card0,
@@ -635,16 +650,18 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
 
             cmatch, rmatch, fb = jax.lax.cond(
                 aug, do_phase, lambda _: (cm0, rm0, jnp.int32(0)), None)
-            return cmatch, rmatch, aug, phases + 1, fallbacks + fb
+            return (cmatch, rmatch, aug, phases + 1, fallbacks + fb,
+                    levels + nlev)
 
         def outer_cond(carry):
-            *_, aug, phases, _ = carry
+            *_, aug, phases, _, _ = carry
             limit = cfg.max_phases if cfg.max_phases > 0 else nc + 2
             return aug & (phases < limit)
 
-        carry = (cmatch, rmatch, jnp.bool_(True), jnp.int32(0), jnp.int32(0))
+        carry = (cmatch, rmatch, jnp.bool_(True), jnp.int32(0), jnp.int32(0),
+                 jnp.int32(0))
         carry = jax.lax.while_loop(outer_cond, outer_body, carry)
-        cmatch, rmatch, aug, phases, fallbacks = carry
+        cmatch, rmatch, aug, phases, fallbacks, levels = carry
         # aug is the last BFS verdict: False means the phase found no
         # augmenting path — Berge certifies the matching maximum.  A
         # budget-truncated exit leaves aug True: valid but uncertified.
@@ -659,6 +676,6 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
             cmatch, rmatch = jax.lax.cond(
                 certified, lambda cr: cr,
                 lambda cr: cheap_init(ecol, cadj, *cr), (cmatch, rmatch))
-        return cmatch, rmatch, phases, fallbacks, certified
+        return cmatch, rmatch, phases, fallbacks, certified, levels
 
     return match_fn

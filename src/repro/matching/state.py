@@ -25,7 +25,8 @@ class MatchState:
 
     ``cmatch`` (nc+1,) / ``rmatch`` (nr+1,): matched partner or -1; the last
     slot is the kernels' scratch sentinel.  ``phases``/``fallbacks`` count the
-    solver's outer iterations (0 for a freshly initialized state).
+    solver's outer iterations and ``levels`` the BFS levels it expanded over
+    all phases (all 0 for a fresh or a warm-started state).
     ``certified`` is the solver's Berge certificate: True iff the last BFS
     phase proved no augmenting path remains, i.e. the matching is maximum —
     a ``MatcherConfig.max_phases``-truncated solve leaves it False (fresh
@@ -38,6 +39,13 @@ class MatchState:
     fallbacks: jax.Array
     certified: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.bool_(False))
+    # None: zeros shaped like ``phases``, for a state rebuilt from a solve's
+    # outputs by a caller that predates the counter
+    levels: Optional[jax.Array] = None
+
+    def __post_init__(self):
+        if self.levels is None and self.phases is not None:
+            object.__setattr__(self, "levels", jnp.zeros_like(self.phases))
 
     @classmethod
     def fresh(cls, nc: int, nr: int, batch_shape: Tuple[int, ...] = ()
@@ -49,7 +57,7 @@ class MatchState:
         rm = rm.at[..., nr].set(SENTINEL)
         zero = jnp.zeros(batch_shape, jnp.int32)
         return cls(cmatch=cm, rmatch=rm, phases=zero, fallbacks=zero,
-                   certified=jnp.zeros(batch_shape, bool))
+                   certified=jnp.zeros(batch_shape, bool), levels=zero)
 
     @classmethod
     def from_host(cls, cmatch: np.ndarray, rmatch: np.ndarray) -> "MatchState":
@@ -60,7 +68,7 @@ class MatchState:
                               jnp.full((1,), SENTINEL)])
         zero = jnp.int32(0)
         return cls(cmatch=cm, rmatch=rm, phases=zero, fallbacks=zero,
-                   certified=jnp.bool_(False))
+                   certified=jnp.bool_(False), levels=zero)
 
     @property
     def cardinality(self) -> jax.Array:
@@ -82,6 +90,7 @@ class MatchStats:
     cardinality: jax.Array
     phases: jax.Array
     fallbacks: jax.Array
+    levels: jax.Array
     certified: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.bool_(False))
     variant: str = dataclasses.field(default="", metadata=dict(static=True))
@@ -90,12 +99,12 @@ class MatchStats:
     def of(cls, state: MatchState, variant: str = "") -> "MatchStats":
         return cls(cardinality=state.cardinality, phases=state.phases,
                    fallbacks=state.fallbacks, certified=state.certified,
-                   variant=variant)
+                   levels=state.levels, variant=variant)
 
     def as_dict(self) -> dict:
         """Host-side stats dict (the old API's ``stats`` payload)."""
         out = {k: np.asarray(getattr(self, k))
-               for k in ("phases", "fallbacks", "cardinality")}
+               for k in ("phases", "fallbacks", "levels", "cardinality")}
         out = {k: int(v) if v.ndim == 0 else v.astype(int)
                for k, v in out.items()}
         cert = np.asarray(self.certified)

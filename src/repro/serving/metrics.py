@@ -1,4 +1,5 @@
-"""Service observability: queue wait, batch occupancy, pad waste, compile hits.
+"""Service observability: queue wait, batch occupancy, pad waste, compile
+hits, host time per serving stage, and lock-step BFS levels.
 
 All counters live behind one lock (``submit`` threads, the flush thread, and
 metric readers race them); latency-shaped series go into bounded reservoirs
@@ -6,13 +7,26 @@ so a long-running service reports percentiles at O(1) memory.  Occupancy and
 pad waste are the two prices the bucketizer/scheduler pay for bounded
 compilation — a deployment watches them to re-size its bucket ladder and
 batch targets.
+
+Stage seconds are cumulative host time in each ``repro.serve.<stage>``
+profiler span of :mod:`repro.serving.service`, recorded where the span ends;
+a stage still open counts up to the snapshot, so the difference of two
+snapshots is the time spent in the stage between them (the flush thread
+sits in ``wait`` from start-up on).  :data:`STAGE_COUNTERS` maps a stage to
+its ``snapshot()`` key.
 """
 from __future__ import annotations
 
 import math
 import threading
+import time
 from collections import deque
-from typing import Iterable, List
+from typing import Iterable, List, Sequence
+
+
+# serving stage (the span ``repro.serve.<stage>``) -> its snapshot() key
+STAGE_COUNTERS = {"admit": "admit_s", "wait": "wait_s", "stack": "stack_s",
+                  "solve": "batch_solve_s", "resolve": "resolve_s"}
 
 
 def percentile(xs: Iterable[float], p: float) -> float:
@@ -59,6 +73,16 @@ class ServiceMetrics:
         # compile-cache deltas attributed to dispatches
         self.compile_hits = 0
         self.compile_misses = 0
+        # host seconds per serving stage, and batched flushes that resolved
+        # (the divisor of the per-flush means; no sharded dispatches, no
+        # failed bisection halves)
+        self.stage_s = dict.fromkeys(STAGE_COUNTERS, 0.0)
+        self._open = {}             # (thread id, stage) -> its start time
+        self.batch_flushes = 0
+        # lock-step BFS levels: sum over real lanes of the lane's levels,
+        # and sum over flushes of real lanes x the deepest real lane's
+        self.lane_levels = 0
+        self.lane_level_slots = 0
         # latency reservoirs (seconds)
         self.queue_wait_s: deque = deque(maxlen=reservoir)
         self.latency_s: deque = deque(maxlen=reservoir)
@@ -80,14 +104,34 @@ class ServiceMetrics:
             self.dispatches += 1
 
     def record_flush(self, reason: str, real: int, padded: int,
-                     hits: int, misses: int) -> None:
+                     hits: int, misses: int, levels: Sequence[int]) -> None:
+        """One resolved batched flush; ``levels`` are its real lanes' BFS
+        levels."""
         with self._lock:
             self.dispatches += 1
+            self.batch_flushes += 1
             self.flushes[reason] = self.flushes.get(reason, 0) + 1
             self.batch_real += real
             self.batch_padded += padded
             self.compile_hits += hits
             self.compile_misses += misses
+            self.lane_levels += sum(levels)
+            self.lane_level_slots += len(levels) * max(levels)
+
+    def stage_begin(self, stage: str) -> float:
+        t = time.perf_counter()
+        with self._lock:
+            self._open[threading.get_ident(), stage] = t
+        return t
+
+    def stage_end(self, stage: str, t0: float, counted: bool = True) -> None:
+        """Close the calling thread's ``stage`` begun at ``t0``; an
+        uncounted stage (one that raised) adds nothing."""
+        t = time.perf_counter()
+        with self._lock:
+            del self._open[threading.get_ident(), stage]
+            if counted:
+                self.stage_s[stage] += t - t0
 
     def record_done(self, queue_wait_s: float, latency_s: float) -> None:
         with self._lock:
@@ -139,6 +183,10 @@ class ServiceMetrics:
         """One consistent host-side view of every counter."""
         with self._lock:
             qs, ls = list(self.queue_wait_s), list(self.latency_s)
+            now = time.perf_counter()
+            stage_s = dict(self.stage_s)
+            for (_, stage), t0 in self._open.items():
+                stage_s[stage] += now - t0
             return {
                 "submitted": self.submitted,
                 "completed": self.completed,
@@ -162,7 +210,11 @@ class ServiceMetrics:
                 "compile_hits": self.compile_hits,
                 "compile_misses": self.compile_misses,
                 "queue_wait_p50_ms": percentile(qs, 50) * 1e3,
-                "queue_wait_p99_ms": percentile(qs, 99) * 1e3,
                 "latency_p50_ms": percentile(ls, 50) * 1e3,
                 "latency_p99_ms": percentile(ls, 99) * 1e3,
+                **{key: stage_s[stage]
+                   for stage, key in STAGE_COUNTERS.items()},
+                "batch_flushes": self.batch_flushes,
+                "lane_levels": self.lane_levels,
+                "lane_level_slots": self.lane_level_slots,
             }

@@ -45,10 +45,27 @@ Fault tolerance (the "failure model & degradation ladder" section of
   with :class:`FlushThreadDiedError`, restarts the thread, and the service
   keeps serving.  :class:`~repro.serving.faults.FaultInjector` drives every
   one of these paths deterministically in tests.
+
+Tracing: each stage is a ``jax.profiler.TraceAnnotation`` span named
+``repro.serve.<stage>``; the host seconds of every stage but ``flush`` are
+a :class:`~repro.serving.metrics.ServiceMetrics` counter read from the same
+clock pair (:data:`~repro.serving.metrics.STAGE_COUNTERS`).  ``admit``
+(the submitting thread, keyword ``seq``: the request's sequence number)
+runs from admission through the enqueue.  On the flush thread ``wait``
+covers the wait for work or a flush deadline, and ``flush`` (keywords
+``first``/``last``: the batch's first and last sequence numbers) one
+dispatched batch, a bisection re-dispatch its own; inside it ``stack``
+(claim, inert-lane padding, ``DeviceCSR.stack``), ``solve`` (``run_many``
+through ``block_until_ready`` and the copy of the lanes' BFS levels) and
+``resolve`` (per-request slicing, ``MatchStats.of`` and ``set_result``
+with the callbacks it runs).  The sharded lane is ``repro.serve.sharded``.
+With the profiler off a span costs one inactive ``TraceMe``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import json
 import os
 import threading
@@ -144,6 +161,7 @@ class _Request:
     warm_start: str
     future: Future
     submitted_at: float
+    seq: int                          # submission number, names its spans
     deadline: Optional[float] = None  # absolute perf_counter() time
     tag: Optional[str] = None
 
@@ -211,6 +229,7 @@ class MatchingService:
         self._ready: List[Flush] = []
         self._sharded_q: List[_Request] = []
         self._taken: List[_Request] = []   # in flight on the flush thread
+        self._seq = itertools.count()
         self._stop = False
         self._thread = self._start_flush_thread()
         self._supervisor: Optional[threading.Thread] = None
@@ -266,6 +285,19 @@ class MatchingService:
         from .warmup import warm_up
         return warm_up(self, grid)
 
+    @contextlib.contextmanager
+    def _stage(self, stage: str, **ids):
+        """The span ``repro.serve.<stage>`` and its seconds counter; a stage
+        that raises is not counted."""
+        t = self.metrics.stage_begin(stage)
+        counted = False
+        try:
+            with jax.profiler.TraceAnnotation(f"repro.serve.{stage}", **ids):
+                yield
+            counted = True
+        finally:
+            self.metrics.stage_end(stage, t, counted)
+
     # -- request intake -------------------------------------------------------
     def submit(self, graph: Union[BipartiteCSR, DeviceCSR], *,
                config: Optional[MatcherConfig] = None,
@@ -289,39 +321,42 @@ class MatchingService:
         cfg = config if config is not None else self.config
         ws = warm_start if warm_start is not None else self.warm_start
         self.matcher(cfg, ws)      # fail fast here, not on the flush thread
-        try:
-            # dirop configs solve through the CSC mirror: admission attaches
-            # it so the dispatched pytree matches what warmup compiled
-            adm = self.bucketizer.admit(graph, csc=cfg.dirop or None)
-        except (OversizeGraphError, GraphValidationError):
-            self.metrics.record_reject()
-            raise
-        now = time.perf_counter()
-        fut: Future = Future()
-        req = _Request(admission=adm, config=cfg, warm_start=ws,
-                       future=fut, submitted_at=now,
-                       deadline=(None if deadline_s is None
-                                 else now + deadline_s),
-                       tag=tag)
-        shed: Optional[_Request] = None
-        with self._cond:
-            if self._stop:
-                raise ServiceClosedError("submit() on a closed service")
-            depth = self._queue_depth_locked()
-            if self.max_queue is not None and depth >= self.max_queue:
-                if self.shed_policy == "reject-newest":
-                    self.metrics.record_shed("reject-newest")
-                    raise QueueFullError(depth, self.max_queue)
-                shed = self._evict_oldest_locked()
-            self.metrics.record_submit(adm.nnz, adm.graph.nnz_pad)
-            if adm.route == "sharded":
-                self._sharded_q.append(req)
-            else:
-                flush = self._batcher.add((adm.bucket, cfg, ws), req,
-                                          req.submitted_at)
-                if flush is not None:
-                    self._ready.append(flush)
-            self._cond.notify_all()
+        seq = next(self._seq)
+        with self._stage("admit", seq=seq):
+            try:
+                # dirop configs solve through the CSC mirror: admission
+                # attaches it so the dispatched pytree matches what warmup
+                # compiled
+                adm = self.bucketizer.admit(graph, csc=cfg.dirop or None)
+            except (OversizeGraphError, GraphValidationError):
+                self.metrics.record_reject()
+                raise
+            now = time.perf_counter()
+            fut: Future = Future()
+            req = _Request(admission=adm, config=cfg, warm_start=ws,
+                           future=fut, submitted_at=now, seq=seq,
+                           deadline=(None if deadline_s is None
+                                     else now + deadline_s),
+                           tag=tag)
+            shed: Optional[_Request] = None
+            with self._cond:
+                if self._stop:
+                    raise ServiceClosedError("submit() on a closed service")
+                depth = self._queue_depth_locked()
+                if self.max_queue is not None and depth >= self.max_queue:
+                    if self.shed_policy == "reject-newest":
+                        self.metrics.record_shed("reject-newest")
+                        raise QueueFullError(depth, self.max_queue)
+                    shed = self._evict_oldest_locked()
+                self.metrics.record_submit(adm.nnz, adm.graph.nnz_pad)
+                if adm.route == "sharded":
+                    self._sharded_q.append(req)
+                else:
+                    flush = self._batcher.add((adm.bucket, cfg, ws), req,
+                                              req.submitted_at)
+                    if flush is not None:
+                        self._ready.append(flush)
+                self._cond.notify_all()
         if shed is not None:
             # resolve OUTSIDE the lock: done-callbacks may re-enter submit
             self.metrics.record_shed("reject-oldest")
@@ -431,7 +466,7 @@ class MatchingService:
 
     def _loop_impl(self) -> None:
         while True:
-            with self._cond:
+            with self._stage("wait"), self._cond:
                 while True:
                     now = time.perf_counter()
                     self._ready.extend(self._batcher.due(now))
@@ -463,7 +498,9 @@ class MatchingService:
                         self._fail([q.payload for q in flush.items], e)
                 for req in sharded:
                     try:
-                        self._dispatch_sharded(req)
+                        with jax.profiler.TraceAnnotation(
+                                "repro.serve.sharded", seq=req.seq):
+                            self._dispatch_sharded(req)
                     except Exception as e:
                         self._fail([req], e)
             except BaseException:
@@ -541,73 +578,93 @@ class MatchingService:
             live.append(r)
         return live
 
-    def _run_batch(self, reqs: List[_Request], cfg: MatcherConfig,
-                   ws: str) -> Tuple[MatchState, int, float, float]:
-        """ONE stacked run_many over ``reqs`` -> (out, padded, t0, done)."""
+    def _stack(self, reqs: List[_Request]) -> Tuple[DeviceCSR, int, float]:
+        """Pad ``reqs``' graphs to the batch rung with inert copies of the
+        first and stack them -> (batch, padded, dispatch start)."""
         t0 = time.perf_counter()
-        if self.faults is not None:
-            self.faults.before_dispatch(reqs)
         graphs = [r.admission.graph for r in reqs]
         padded = batch_bucket(len(graphs), self._batcher.max_batch)
         graphs = graphs + [graphs[0]] * (padded - len(graphs))  # inert lanes
-        batch = DeviceCSR.stack(graphs)
-        out = self.matcher(cfg, ws).run_many(batch)
-        jax.block_until_ready(out.cmatch)
-        return out, padded, t0, time.perf_counter()
+        return DeviceCSR.stack(graphs), padded, t0
 
     def _dispatch(self, flush: Flush) -> None:
         """One flushed bucket: claim, shed expired, then batch-dispatch
         with bisection recovery."""
         bucket, cfg, ws = flush.key
-        reqs = self._claim([q.payload for q in flush.items])
-        if not reqs:
-            return
-        self._dispatch_reqs(reqs, bucket, cfg, ws, flush.reason)
+        self._dispatch_reqs([q.payload for q in flush.items], bucket, cfg,
+                            ws, flush.reason, claim=True)
 
     def _dispatch_reqs(self, reqs: List[_Request], bucket, cfg, ws,
-                       reason: str, depth: int = 0) -> None:
+                       reason: str, depth: int = 0,
+                       claim: bool = False) -> None:
         """Dispatch ``reqs`` as one batch; on failure, isolate the poison.
 
+        ``claim`` (a flush's first dispatch) first claims the futures and
+        sheds the expired ones (:meth:`_claim`), inside the ``stack`` span.
         A multi-request batch that fails is split in half and each half
         re-dispatched after a bounded exponential backoff — innocent
         co-batched requests land in an all-good half within O(log batch)
-        re-dispatches and succeed.  A singleton that still fails after
-        ``dispatch_retries`` retries is the isolated poisoned request: its
-        future gets the real error and a quarantine artifact is dumped.
+        re-dispatches and succeed.  A singleton — counted after the claim —
+        that still fails after ``dispatch_retries`` retries is the isolated
+        poisoned request: its future gets the real error and a quarantine
+        artifact is dumped.
         """
-        retries = self.dispatch_retries if len(reqs) == 1 else 0
-        for attempt in range(retries + 1):
+        attempt = 0
+        while True:
             if depth or attempt:
                 time.sleep(min(0.2, self.retry_backoff_s
                                * (2 ** (depth + attempt - 1))))
-            info0 = compile_cache_thread_info()
-            try:
-                out, padded, t0, done = self._run_batch(reqs, cfg, ws)
-            except FlushThreadDeath:
-                raise                       # a crash is not a request error
-            except Exception as e:
-                if attempt < retries:
-                    continue
-                if len(reqs) == 1:
-                    self._quarantine(reqs[0], e)
-                    return
-                mid = len(reqs) // 2
-                self._dispatch_reqs(reqs[:mid], bucket, cfg, ws, reason,
-                                    depth + 1)
-                self._dispatch_reqs(reqs[mid:], bucket, cfg, ws, reason,
-                                    depth + 1)
-                return
-            break
-        info1 = compile_cache_thread_info()
-        self._resolve_batch(reqs, out, padded, bucket, cfg, reason, t0, done,
+            with jax.profiler.TraceAnnotation(
+                    "repro.serve.flush", first=reqs[0].seq, last=reqs[-1].seq):
+                info0 = compile_cache_thread_info()
+                try:
+                    with self._stage("stack"):
+                        if claim:
+                            reqs, claim = self._claim(reqs), False
+                            if not reqs:
+                                return
+                        batch, padded, t0 = self._stack(reqs)
+                    with self._stage("solve"):
+                        if self.faults is not None:
+                            self.faults.before_dispatch(reqs)
+                        out = self.matcher(cfg, ws).run_many(batch)
+                        jax.block_until_ready(out.cmatch)
+                        # one copy of the lanes' levels; each request's
+                        # result takes its lane's from the host
+                        out = dataclasses.replace(
+                            out, levels=np.asarray(out.levels))
+                    done = time.perf_counter()
+                except FlushThreadDeath:
+                    raise                   # a crash is not a request error
+                except Exception as e:
+                    error = e
+                else:
+                    info1 = compile_cache_thread_info()
+                    with self._stage("resolve"):
+                        self._resolve_batch(
+                            reqs, out, padded, bucket, cfg, reason, t0, done,
                             hits=info1["hits"] - info0["hits"],
                             misses=info1["misses"] - info0["misses"])
+                    return
+            if len(reqs) == 1 and attempt < self.dispatch_retries:
+                attempt += 1
+                continue
+            if len(reqs) == 1:
+                self._quarantine(reqs[0], error)
+                return
+            mid = len(reqs) // 2
+            self._dispatch_reqs(reqs[:mid], bucket, cfg, ws, reason,
+                                depth + 1)
+            self._dispatch_reqs(reqs[mid:], bucket, cfg, ws, reason,
+                                depth + 1)
+            return
 
     def _resolve_batch(self, reqs, out, padded, bucket, cfg, reason,
                        t0: float, done: float, hits: int = 0,
                        misses: int = 0) -> None:
         self.metrics.record_flush(reason, real=len(reqs), padded=padded,
-                                  hits=hits, misses=misses)
+                                  hits=hits, misses=misses,
+                                  levels=out.levels[:len(reqs)].tolist())
         for i, r in enumerate(reqs):
             state = jax.tree.map(lambda x: x[i], out)
             qw = t0 - r.submitted_at

@@ -127,6 +127,25 @@ def test_singleton_retry_absorbs_transient_fault():
     assert faults.injected == 1                  # the fault did fire
 
 
+@pytest.mark.parametrize("drop", ["cancelled", "expired"])
+def test_claim_cut_to_a_singleton_keeps_its_retry(drop):
+    # a flush of two that the claim cuts to one request is a singleton:
+    # its transient fault is retried, not quarantined
+    faults = FaultInjector(seed=8)
+    g1, g2 = graphs(2)
+    with make_service(faults=faults) as svc:     # 60ms delay: one flush
+        gone = svc.submit(g1, deadline_s=0.0 if drop == "expired" else None)
+        if drop == "cancelled":
+            assert gone.cancel()
+        faults.script(RuntimeError("transient device hiccup"))
+        res = svc.submit(g2).result(timeout=300)
+        snap = svc.metrics.snapshot()
+    assert res.cardinality > 0 and res.batch_size == 1
+    assert snap["quarantined"] == 0 and snap["failed"] == 0
+    assert faults.injected == 1
+    check_sum_invariant(snap)
+
+
 # ---------------------------------------------------------------------------
 # restart: flush-thread death -> supervisor fail-over + restart
 # ---------------------------------------------------------------------------
