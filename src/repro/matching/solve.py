@@ -9,10 +9,12 @@ level is a single *edge-parallel* vector operation over all ``nnz`` edges:
 * the per-thread race "first writer wins" becomes a deterministic
   ``min``-merge (lowest proposing column wins) — same semantics class the
   paper relies on, but reproducible.  Three interchangeable sweeps produce
-  the identical per-row winner vector: the jnp path (proposals + XLA
-  scatter), the legacy Pallas path (proposal kernel + XLA scatter) and the
-  fused Pallas path (winner accumulator merged inside the kernel, no (nnz,)
-  intermediate — the default when ``use_pallas``);
+  the identical per-row winner vector: the jnp path (the XLA sweep the chip
+  runs: the column side of the proposal predicate gathered once per edge,
+  the scatter-min by row, then the row side applied to the O(nr) winner
+  vector), the legacy Pallas path (per-edge proposal kernel + XLA scatter)
+  and the fused Pallas path (winner accumulator merged inside the kernel,
+  no (nnz,) intermediate — the default when ``use_pallas``);
 * beyond-paper, ``adaptive_frontier`` tracks the frontier size each level
   and swaps the dense O(nnz) sweep for a compact column-gather sweep
   (O(cap·dmax)) whenever the frontier is small enough, with a runtime
@@ -113,29 +115,56 @@ def default_block_edges(nnz_pad: int, schedule: str) -> int:
 def _winner_full(ecol, cadj, bfs, root, rmatch, level, nr, *, use_pallas: bool,
                  pallas_fused: bool, block_edges: int,
                  interpret: Optional[bool]):
-    """Dense O(nnz) sweep -> per-row winner vector (nr+1,)."""
-    if use_pallas and pallas_fused:
+    """Dense O(nnz) sweep -> per-row winner vector (nr+1,): the XLA sweep
+    (one column-side gather per edge, the scatter-min, the row side on the
+    winner vector) unless ``use_pallas``."""
+    if not use_pallas:
+        return _winner_xla(ecol, cadj, bfs, root, rmatch, level, nr)
+    if pallas_fused:
         from repro.kernels.frontier_expand.ops import frontier_expand_fused
         return frontier_expand_fused(ecol, cadj, bfs, root, rmatch, level,
                                      block_edges=block_edges,
                                      interpret=interpret)
-    if use_pallas:
-        from repro.kernels.frontier_expand.ops import frontier_expand
-        prop = frontier_expand(ecol, cadj, bfs, root, rmatch, level,
-                               block_edges=block_edges, interpret=interpret)
-    else:
-        target = _proposal_mask(ecol, cadj, bfs, root, rmatch, level)
-        prop = jnp.where(target, ecol, IINF)          # per-edge proposal
-    row_ix = jnp.where(prop < IINF, cadj, nr)
-    return scatter_min(nr, row_ix, prop)
+    from repro.kernels.frontier_expand.ops import frontier_expand
+    prop = frontier_expand(ecol, cadj, bfs, root, rmatch, level,
+                           block_edges=block_edges, interpret=interpret)
+    return scatter_min(nr, jnp.where(prop < IINF, cadj, nr), prop)
 
 
-def _proposal_mask(ecol, cadj, bfs, root, rmatch, level):
-    """Per-edge proposal predicate — the ONE formula the kernels tile
-    (shared so jnp-vs-Pallas parity cannot drift; the jnp oracle in
-    kernels/frontier_expand/ref.py stays an independent copy on purpose)."""
-    from repro.kernels.frontier_expand.frontier_expand import _proposals
-    return _proposals(level, ecol, cadj, bfs, root, rmatch)
+def _winner_xla(ecol, cadj, bfs, root, rmatch, level, nr):
+    """The XLA sweep: the proposal predicate factored into its two sides.
+
+    An edge (c, r) proposes iff ``_active_cols[c] & _unreached_rows[r]``,
+    and a row's winner is the min over its proposing edges, so the row side
+    can wait for the winner vector.  Per edge this is ONE gather of the
+    column side's value (the column, or IINF), then the scatter-min by row;
+    both sides are O(n) work.  Bit-identical to scatter-merging the per-edge
+    ``_proposals`` formula the Pallas kernels tile.  ``ecol``/``cadj`` may
+    be any edge order (the CSC mirror's ``radj``/``erow`` too); padding
+    edges (``ecol = nc``, ``cadj = nr``) read the sentinel column, which
+    never proposes, into the discard slot.
+    """
+    cols = jnp.arange(bfs.shape[0], dtype=jnp.int32)
+    colval = jnp.where(_active_cols(bfs, root, level), cols, IINF)
+    prop = colval[ecol]                                  # the one edge gather
+    # IINF is the min identity, so a non-proposing edge scatters it to its
+    # own row; routing all of them to the discard slot measured slower on
+    # a TPU v5e (the scatter took 20-25% longer)
+    winner = scatter_min(nr, cadj, prop)
+    rowok = jnp.append(_unreached_rows(bfs, rmatch), False)
+    return jnp.where(rowok, winner, IINF)
+
+
+def _active_cols(bfs, root, level):
+    """The (nc+1,) column side of the proposal predicate: columns on the
+    frontier at ``level`` whose BFS tree has not found its augmenting path
+    yet (the GPUBFS-WR early exit, when ``root`` is given).  False at the
+    sentinel slot, whose level is NEG."""
+    nc = bfs.shape[0] - 1
+    ok = bfs == level
+    if root is not None:
+        ok &= bfs[jnp.clip(root, 0, nc)] >= UNVISITED
+    return ok
 
 
 def _unreached_rows(bfs, rmatch):
@@ -162,10 +191,7 @@ def _winner_pull_compact(rxadj, radj, bfs, root, rmatch, level, nr,
     """
     nc = bfs.shape[0] - 1
     nnz_pad = radj.shape[0]
-    # the column side of the proposal predicate, for every column at once
-    colok = bfs == level                                         # (nc+1,)
-    if root is not None:
-        colok &= bfs[jnp.clip(root, 0, nc)] >= UNVISITED
+    colok = _active_cols(bfs, root, level)                       # (nc+1,)
     rows = jnp.nonzero(unreached, size=cap, fill_value=nr)[0]    # (cap,)
     starts = rxadj[jnp.minimum(rows, nr)]
     ends = rxadj[jnp.minimum(rows + 1, nr)]                      # fill -> deg 0
@@ -195,10 +221,7 @@ def _winner_pull_stream(radj, erow, bfs, root, rmatch, level, nr, *,
         return frontier_expand_pull(radj, erow, bfs, root, rmatch, level,
                                     block_edges=block_edges,
                                     interpret=interpret)
-    target = _proposal_mask(radj, erow, bfs, root, rmatch, level)
-    prop = jnp.where(target, radj, IINF)
-    row_ix = jnp.where(target, erow, nr)
-    return scatter_min(nr, row_ix, prop)
+    return _winner_xla(radj, erow, bfs, root, rmatch, level, nr)
 
 
 def _winner_compact(cxadj, cadj, bfs, rmatch, nr, isf, *,
@@ -291,7 +314,6 @@ def _expand_level(ecol, cadj, bfs, root, pred, rmatch, level, *, wr: bool,
     compact geometry must be resolved through ``MatcherConfig`` (0 = not
     resolved is an error here — there is no untracked default).
     """
-    nc = bfs.shape[0] - 1
     nr = pred.shape[0] - 1
     rt = root if wr else None
 
@@ -306,9 +328,7 @@ def _expand_level(ecol, cadj, bfs, root, pred, rmatch, level, *, wr: bool,
         assert compact_cap > 0 and compact_dmax > 0, \
             "resolve the compact geometry via MatcherConfig.resolve_cap/" \
             "resolve_dmax (0 means unresolved, not a default)"
-        isf = bfs[:-1] == level
-        if wr:
-            isf &= bfs[jnp.clip(root[:-1], 0, nc)] >= UNVISITED
+        isf = _active_cols(bfs, rt, level)[:-1]
         deg = cxadj[1:] - cxadj[:-1]
         eligible = ((jnp.sum(isf.astype(jnp.int32)) <= compact_cap)
                     & (jnp.max(jnp.where(isf, deg, 0)) <= compact_dmax))
@@ -354,7 +374,6 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
     shard takes the same branch).  Returns the updated state plus this
     level's direction for the next level's hysteresis.
     """
-    nc = bfs.shape[0] - 1
     nr = pred.shape[0] - 1
     rt = root if wr else None
 
@@ -363,9 +382,7 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
                             use_pallas=use_pallas, pallas_fused=pallas_fused,
                             block_edges=block_edges, interpret=interpret)
 
-    isf = bfs[:-1] == level
-    if wr:
-        isf &= bfs[jnp.clip(root[:-1], 0, nc)] >= UNVISITED
+    isf = _active_cols(bfs, rt, level)[:-1]
     cdeg = cxadj[1:] - cxadj[:-1]
     fe = jnp.sum(jnp.where(isf, cdeg, 0)).astype(jnp.float32)
     unreached = _unreached_rows(bfs, rmatch)

@@ -7,6 +7,9 @@ fixes and the ALTERNATE micro-optimizations.
 Split by concern:
 * kernel-level: fused winners == scatter_min(legacy proposals) == fused ref
   == pull winners over the CSC-permuted edges;
+* the XLA sweep: its column-side gather plus row-side mask == scatter_min of
+  the per-edge proposal formula, on random mid-phase states, WR and plain,
+  alone and under vmap;
 * CSC mirror: `DeviceCSR.with_csc` agrees with the host transpose and rides
   every shape operation (pad_to / pad_vertices / stack);
 * solver-level: jnp / Pallas-interpret / adaptive / dirop sweeps give
@@ -39,7 +42,9 @@ from repro.kernels.frontier_expand import (frontier_expand,
                                            frontier_expand_pull_ref,
                                            resolve_interpret)
 from repro.matching import DeviceCSR, Matcher, SOLVE_PATHS
-from repro.matching.solve import (IINF, _alternate, default_block_edges,
+from repro.kernels.frontier_expand.frontier_expand import _proposals
+from repro.matching.solve import (FOUND, IINF, NEG, UNVISITED, _alternate,
+                                  _winner_full, default_block_edges,
                                   level0_state, scatter_min)
 
 def _bfs_state(g):
@@ -96,6 +101,73 @@ def test_pull_kernel_bit_identical_to_push_winners(nc, nr, deg, pad, blk):
                                        jnp.int32(2))
         np.testing.assert_array_equal(np.asarray(pull), np.asarray(push))
         np.testing.assert_array_equal(np.asarray(pull), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# the XLA sweep: the proposal predicate factored into a column and a row side
+# ---------------------------------------------------------------------------
+def _mid_phase_state(seed, nc=150, nr=170, nnz=1200, pad=200, level=3):
+    """A random BFS state at ``level`` with every kind of column (frontier,
+    visited earlier or just now, UNVISITED, FOUND, exact-WR endpoint
+    encodings -(r+1)) and of row (unmatched, endpoint -2, matched to a
+    column of each kind), over random edges plus padding edges (``ecol =
+    nc``, ``cadj = nr``) scattered through the edge order."""
+    rng = np.random.default_rng(seed)
+    ecol = np.concatenate([rng.integers(0, nc, nnz), np.full(pad, nc)])
+    cadj = np.concatenate([rng.integers(0, nr, nnz), np.full(pad, nr)])
+    order = rng.permutation(nnz + pad)
+    bfs = rng.choice([level, level, level - 1, level + 1, int(UNVISITED),
+                      int(UNVISITED), int(FOUND)], nc + 1)
+    enc = rng.random(nc + 1) < 0.1
+    bfs[enc] = -rng.integers(1, nr + 1, int(enc.sum()))
+    bfs[nc] = int(NEG)
+    root = rng.integers(0, nc + 1, nc + 1)
+    root[nc] = nc
+    rmatch = rng.choice([-1, -2, 0], nr + 1, p=[0.3, 0.1, 0.6])
+    matched = rmatch == 0
+    rmatch[matched] = rng.integers(0, nc, int(matched.sum()))
+    rmatch[nr] = -3
+    kinds = {"frontier": bfs == level, "found": bfs == FOUND,
+             "exact": bfs < 0, "unvisited": bfs == UNVISITED}
+    assert all(k[:nc].any() for k in kinds.values())
+    assert {-1, -2} <= set(rmatch[:nr].tolist()) and (rmatch[:nr] >= 0).any()
+    i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    return (i32(ecol[order]), i32(cadj[order]), i32(bfs), i32(root),
+            i32(rmatch), jnp.int32(level))
+
+
+def _winner_per_edge(ecol, cadj, bfs, root, rmatch, level):
+    """The per-edge formula the Pallas kernels tile, merged by scatter_min."""
+    nr = rmatch.shape[0] - 1
+    t = _proposals(level, ecol, cadj, bfs, root, rmatch)
+    return scatter_min(nr, jnp.where(t, cadj, nr), jnp.where(t, ecol, IINF))
+
+
+def _winner_factored(ecol, cadj, bfs, root, rmatch, level):
+    return _winner_full(ecol, cadj, bfs, root, rmatch, level,
+                        rmatch.shape[0] - 1, use_pallas=False,
+                        pallas_fused=False, block_edges=128, interpret=None)
+
+
+@pytest.mark.parametrize("batch", [0, 4], ids=["one", "vmap"])
+@pytest.mark.parametrize("wr", [True, False], ids=["wr", "plain"])
+def test_xla_sweep_factored_winners_bit_identical(wr, batch):
+    seeds = range(3) if batch == 0 else [list(range(s, s + batch))
+                                         for s in (10, 20)]
+    for seed in seeds:
+        if batch == 0:
+            args = _mid_phase_state(seed)
+            ref, got = _winner_per_edge, _winner_factored
+        else:
+            args = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                *[_mid_phase_state(s) for s in seed])
+            ref, got = jax.vmap(_winner_per_edge), jax.vmap(_winner_factored)
+        if not wr:
+            args = args[:3] + (None,) + args[4:]
+        want = np.asarray(ref(*args))
+        assert (want < IINF).any() and (want == IINF).any()
+        np.testing.assert_array_equal(np.asarray(got(*args)), want,
+                                      err_msg=f"seed {seed}")
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ program over the four chips of a v5e 2x2, whose edge arrays must be spread
 a quarter per chip.  The Pallas frontier kernels must still be rejected by
 the TPU compiler: that rejection is what ``MatcherConfig.canonical`` refuses
 compiled Pallas for, so when a kernel compiles here this file fails and the
-guard comes out.
+guard comes out.  The single-chip programs also pin the XLA sweep's shape:
+one gather per edge slot in each BFS level.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers import every file.
@@ -68,6 +69,27 @@ def _graph(nc, nr, nnz, sharding, edge_sharding=None, batch=()):
                      ecol=leaf((nnz,), edges), nnz=leaf(()), nc=nc, nr=nr)
 
 
+def _edge_gathers(hlo: str, scope: str) -> int:
+    """Gathers with an edge-slot result (``s32[NNZ]``) under ``scope``, in
+    the compiled text: by the gather's own ``op_name``, or by that of the
+    fusion that calls the computation holding it."""
+    comp, caller_op, gathers = None, {}, []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        call = re.search(r"calls=%([\w.-]+)", line)
+        if call:
+            caller_op[call.group(1)] = op
+        if re.search(rf"= s32\[{NNZ}\]\{{[^}}]*\}} gather\(", line):
+            gathers.append((comp, op))
+    return sum(scope in op or scope in caller_op.get(c, "")
+               for c, op in gathers)
+
+
 def _state(graph, sharding):
     shapes = jax.eval_shape(lambda: empty_like_graph(graph))
     return jax.tree.map(
@@ -83,12 +105,16 @@ def test_matcher_run_compiles_for_v5e(one_chip, cfg):
     g = _graph(N, N, NNZ, one_chip)
     st = _state(g, one_chip)
     matcher = Matcher(cfg, warm_start="karp_sipser")
-    mem = matcher.program(g).lower(g, st).compile().memory_analysis()
+    compiled = matcher.program(g).lower(g, st).compile()
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= EDGE_BYTES
     # the whole solve (warm start fused in) keeps well under 1 GiB of the
     # chip's 16 GB: the O(nnz) edges plus O(n) state, no (nnz,) blow-up
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 1 << 30, mem
+    # the XLA sweep reads one column-side value per edge slot per level;
+    # the row side of the proposal predicate waits for the winner vector
+    assert _edge_gathers(compiled.as_text(), "bfs_level") == 1
 
 
 def test_match_many_compiles_for_v5e_at_a_serving_bucket(one_chip):
