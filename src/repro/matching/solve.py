@@ -341,7 +341,8 @@ def _expand_level(ecol, cadj, bfs, root, pred, rmatch, level, *, wr: bool,
         winner = full(None)
 
     if axis is not None:                                  # merge edge shards
-        winner = jax.lax.pmin(winner, axis)
+        with jax.named_scope("merge_shards"):
+            winner = jax.lax.pmin(winner, axis)
     return _apply_winner(winner, bfs, root, pred, rmatch, level, wr=wr,
                          wr_exact=wr_exact)
 
@@ -405,7 +406,8 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
 
     winner = jax.lax.cond(use_pull, pull, full, None)
     if axis is not None:                                  # merge edge shards
-        winner = jax.lax.pmin(winner, axis)
+        with jax.named_scope("merge_shards"):
+            winner = jax.lax.pmin(winner, axis)
     return _apply_winner(winner, bfs, root, pred, rmatch, level, wr=wr,
                          wr_exact=wr_exact) + (use_pull,)
 
@@ -514,7 +516,8 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
 
     The program carries ``jax.named_scope`` names for the trace: ``phase``
     (one outer iteration), ``bfs_level`` (one level's sweep), ``alternate``
-    and ``fix_matching``.  They are metadata only; the last component of
+    and ``fix_matching``, and with ``axis`` set ``merge_shards`` (the
+    level's ``pmin``).  They are metadata only; the last component of
     every ``op_name`` stays the JAX primitive.
 
     Shape-polymorphic: ``nc``/``nr``/``block_edges`` are derived from the

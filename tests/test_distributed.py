@@ -146,8 +146,39 @@ for algo in ("apfb", "apsb"):
 print("DIST_OK")
 """
 
+# The benchmark's four-chip deployment at a small size: Karp-Sipser on a
+# uniform graph, the sharded solve bit-identical to one device in its
+# matching, its phases and its BFS levels; and the compiled program merges
+# the shards of each level with one all-reduce under ``merge_shards``, the
+# only one in a BFS level.
+MERGE = PRELUDE + """
+import re
+from repro.core.oracles import hopcroft_karp
+from repro.matching.state import empty_like_graph
+g = random_bipartite(1 << 12, 1 << 12, 8.0, seed=5)
+graph = DeviceCSR.from_host(g)
+sharded_g = graph.shard(mesh, "data")
+single = Matcher(MatcherConfig(), warm_start="karp_sipser").run(graph)
+sm = ShardedMatcher(mesh, config=MatcherConfig(), warm_start="karp_sipser")
+st = sm.run(sharded_g)
+np.testing.assert_array_equal(np.asarray(st.cmatch), np.asarray(single.cmatch))
+np.testing.assert_array_equal(np.asarray(st.rmatch), np.asarray(single.rmatch))
+assert int(st.phases) == int(single.phases), (st.phases, single.phases)
+assert int(st.levels) == int(single.levels) > 0, (st.levels, single.levels)
+cm, rm = st.to_host()
+hk = int((hopcroft_karp(g)[0] >= 0).sum())
+assert validate_matching(g, cm, rm) == hk, (hk,)
+hlo = sm.program(sharded_g).lower(
+    sharded_g, empty_like_graph(sharded_g)).compile().as_text()
+ops = [re.search(r'op_name="([^"]*)"', l).group(1) for l in hlo.splitlines()
+       if re.search(r" all-reduce(-start)?\\(", l) and "op_name=" in l]
+level = [op for op in ops if "/bfs_level/" in op]
+assert level and all("/bfs_level/merge_shards/" in op for op in level), ops
+print("DIST_OK")
+"""
+
 SCENARIOS = {"equality": EQUALITY, "cache": CACHE, "pallas": PALLAS,
-             "dirop": DIROP, "compat": COMPAT}
+             "dirop": DIROP, "compat": COMPAT, "merge": MERGE}
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))

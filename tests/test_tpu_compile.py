@@ -143,6 +143,45 @@ def test_sharded_matcher_spreads_edges_over_four_v5e_chips(topo):
     assert mem.argument_size_in_bytes <= EDGE_BYTES // 4 + replicated + 4096, mem
 
 
+def _merge_operands(hlo: str):
+    """The result type of every operand of each all-reduce whose ``op_name``
+    lies under ``merge_shards``, by the operand's own definition."""
+    types = {}
+    merges = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (\S+) ([\w-]+)\(([^)]*)\)",
+                     line)
+        if not m:
+            continue
+        types[m.group(1)] = m.group(2)
+        if (m.group(3).startswith("all-reduce")
+                and "/merge_shards/" in line):
+            merges.append(re.findall(r"%([\w.-]+)", m.group(4)))
+    return [[types[op] for op in ops] for ops in merges]
+
+
+def test_sharded_cell_merges_one_winner_vector_per_level(topo, one_chip):
+    """The four-chip benchmark cell's program (Karp-Sipser, 2^21 per side,
+    2^24 edge slots over a v5e 2x2): the merge of each BFS level is an
+    all-reduce of the (nr+1) int32 winner vector; the one-chip program
+    has no merge at all."""
+    n, nnz = 1 << 21, 1 << 24
+    mesh = jax.make_mesh((4,), ("data",), devices=topo.devices)
+    sm = ShardedMatcher(mesh, config=MatcherConfig(), warm_start="karp_sipser")
+    rep = NamedSharding(sm.mesh, P())
+    g = _graph(n, n, nnz, rep, edge_sharding=NamedSharding(sm.mesh, P("data")))
+    hlo = sm.program(g).lower(g, _state(g, rep)).compile().as_text()
+    operands = _merge_operands(hlo)
+    assert operands, "no all-reduce under merge_shards"
+    for types in operands:
+        assert types and all(re.match(rf"s32\[{n + 1}\]\{{", t)
+                             for t in types), types
+    g1 = _graph(1 << 18, 1 << 18, 1 << 21, one_chip)
+    one = (Matcher(MatcherConfig(), warm_start="karp_sipser").program(g1)
+           .lower(g1, _state(g1, one_chip)).compile().as_text())
+    assert "merge_shards" not in one
+
+
 @pytest.mark.parametrize("wr", [True, False], ids=["wr", "plain"])
 @pytest.mark.parametrize("kernel", [frontier_expand, frontier_expand_fused,
                                     frontier_expand_pull],
