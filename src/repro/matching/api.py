@@ -20,7 +20,7 @@ from .config import MatcherConfig
 from .device_csr import DeviceCSR
 from .solve import make_solver
 from .state import MatchState, MatchStats, empty_like_graph
-from .warmstart import get_warm_start, warm_start_version
+from .warmstart import apply_warm_start, get_warm_start, warm_start_version
 
 
 class Matcher:
@@ -68,9 +68,10 @@ class Matcher:
     def _init_pure(self, graph: DeviceCSR, state: MatchState) -> MatchState:
         self._check_state(graph, state)
         with jax.named_scope("warm_start"):
-            cm, rm = get_warm_start(self.warm_start)(
-                graph.ecol, graph.cadj, state.cmatch, state.rmatch)
-        return dataclasses.replace(state, cmatch=cm, rmatch=rm)
+            cm, rm, rounds = apply_warm_start(self.warm_start, graph,
+                                              state.cmatch, state.rmatch)
+        return dataclasses.replace(state, cmatch=cm, rmatch=rm,
+                                   warm_rounds=state.warm_rounds + rounds)
 
     def solve(self, graph: DeviceCSR, state: MatchState) -> MatchState:
         """Run the solver from ``state`` (pure; no warm start applied)."""
@@ -90,7 +91,8 @@ class Matcher:
         return MatchState(cmatch=cm, rmatch=rm,
                           phases=state.phases + phases,
                           fallbacks=state.fallbacks + fb,
-                          certified=cert, levels=state.levels + levels)
+                          certified=cert, levels=state.levels + levels,
+                          warm_rounds=state.warm_rounds)
 
     def _cache_tag(self, cold: bool):
         """Warm-start identity for the compile cache; versioned so that

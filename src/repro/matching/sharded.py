@@ -49,7 +49,7 @@ from .config import MatcherConfig
 from .device_csr import DeviceCSR, auto_mesh
 from .solve import make_solver
 from .state import MatchState, MatchStats, empty_like_graph
-from .warmstart import get_warm_start
+from .warmstart import apply_warm_start, cheap_init
 
 
 def mesh_cache_key(mesh: Mesh, axis: str):
@@ -136,15 +136,16 @@ class ShardedMatcher(Matcher):
             smap = jax.shard_map(
                 solve, mesh=self.mesh, in_specs=in_specs,
                 out_specs=(P(),) * 6, check_vma=False)
-            init = get_warm_start(self.warm_start)
             cfg = self.config
 
             def fn(g: DeviceCSR, s: MatchState) -> MatchState:
                 self._check_state(g, s)
-                cm, rm = s.cmatch, s.rmatch
+                cm, rm, rounds = s.cmatch, s.rmatch, s.warm_rounds
                 if cold:
                     with jax.named_scope("warm_start"):
-                        cm, rm = init(g.ecol, g.cadj, cm, rm)
+                        cm, rm, ran = apply_warm_start(self.warm_start, g,
+                                                       cm, rm)
+                    rounds = rounds + ran
                 extra = ((g.cxadj, g.rxadj, g.radj, g.erow) if dirop else ())
                 cm, rm, phases, fb, cert, levels = smap(g.ecol, g.cadj, cm,
                                                         rm, *extra)
@@ -154,15 +155,16 @@ class ShardedMatcher(Matcher):
                     # region: cheap_init's scatter rounds need the whole
                     # edge list, and like the warm start GSPMD partitions
                     # them over the sharded arrays automatically.
-                    from .warmstart import cheap_init
                     cm, rm = jax.lax.cond(
                         cert, lambda cr: cr,
-                        lambda cr: cheap_init(g.ecol, g.cadj, *cr),
+                        lambda cr: cheap_init(g.ecol, g.cadj, *cr,
+                                              cxadj=g.cxadj),
                         (cm, rm))
                 return MatchState(cmatch=cm, rmatch=rm,
                                   phases=s.phases + phases,
                                   fallbacks=s.fallbacks + fb,
-                                  certified=cert, levels=s.levels + levels)
+                                  certified=cert, levels=s.levels + levels,
+                                  warm_rounds=rounds)
 
             return fn
 

@@ -695,7 +695,8 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
             from .warmstart import cheap_init
             cmatch, rmatch = jax.lax.cond(
                 certified, lambda cr: cr,
-                lambda cr: cheap_init(ecol, cadj, *cr), (cmatch, rmatch))
+                lambda cr: cheap_init(ecol, cadj, *cr, cxadj=cxadj),
+                (cmatch, rmatch))
         return cmatch, rmatch, phases, fallbacks, certified, levels
 
     return match_fn

@@ -26,7 +26,9 @@ class MatchState:
     ``cmatch`` (nc+1,) / ``rmatch`` (nr+1,): matched partner or -1; the last
     slot is the kernels' scratch sentinel.  ``phases``/``fallbacks`` count the
     solver's outer iterations and ``levels`` the BFS levels it expanded over
-    all phases (all 0 for a fresh or a warm-started state).
+    all phases (all 0 for a fresh or a warm-started state); ``warm_rounds``
+    counts the rounds the warm start ran (Karp–Sipser degree rounds plus
+    cheap rounds; 0 for ``"none"`` and for a state not warm-started).
     ``certified`` is the solver's Berge certificate: True iff the last BFS
     phase proved no augmenting path remains, i.e. the matching is maximum —
     a ``MatcherConfig.max_phases``-truncated solve leaves it False (fresh
@@ -42,10 +44,12 @@ class MatchState:
     # None: zeros shaped like ``phases``, for a state rebuilt from a solve's
     # outputs by a caller that predates the counter
     levels: Optional[jax.Array] = None
+    warm_rounds: Optional[jax.Array] = None
 
     def __post_init__(self):
-        if self.levels is None and self.phases is not None:
-            object.__setattr__(self, "levels", jnp.zeros_like(self.phases))
+        for name in ("levels", "warm_rounds"):
+            if getattr(self, name) is None and self.phases is not None:
+                object.__setattr__(self, name, jnp.zeros_like(self.phases))
 
     @classmethod
     def fresh(cls, nc: int, nr: int, batch_shape: Tuple[int, ...] = ()
@@ -57,7 +61,8 @@ class MatchState:
         rm = rm.at[..., nr].set(SENTINEL)
         zero = jnp.zeros(batch_shape, jnp.int32)
         return cls(cmatch=cm, rmatch=rm, phases=zero, fallbacks=zero,
-                   certified=jnp.zeros(batch_shape, bool), levels=zero)
+                   certified=jnp.zeros(batch_shape, bool), levels=zero,
+                   warm_rounds=zero)
 
     @classmethod
     def from_host(cls, cmatch: np.ndarray, rmatch: np.ndarray) -> "MatchState":
@@ -68,7 +73,7 @@ class MatchState:
                               jnp.full((1,), SENTINEL)])
         zero = jnp.int32(0)
         return cls(cmatch=cm, rmatch=rm, phases=zero, fallbacks=zero,
-                   certified=jnp.bool_(False), levels=zero)
+                   certified=jnp.bool_(False), levels=zero, warm_rounds=zero)
 
     @property
     def cardinality(self) -> jax.Array:

@@ -177,8 +177,39 @@ assert level and all("/bfs_level/merge_shards/" in op for op in level), ops
 print("DIST_OK")
 """
 
+# The Karp-Sipser warm start on edge-sharded arrays (GSPMD partitions its
+# segment scans and its per-edge gather and scatter by row): the same
+# warm-start matching and the same count of rounds as on one device, and
+# ShardedMatcher carries that count into the solved state.
+WARM_ROUNDS = PRELUDE + """
+from repro.graphs import kron_graph
+from repro.matching.state import empty_like_graph
+from repro.matching.warmstart import apply_warm_start
+for g in (kron_graph(10, 8, seed=6), cases["free"]):
+    graph = DeviceCSR.from_host(g)
+    sharded_g = graph.shard(mesh, "data")
+    one = Matcher(MatcherConfig(), warm_start="karp_sipser")
+    warm = one.init(graph)
+    cm, rm, rounds = jax.jit(lambda g, s: apply_warm_start(
+        "karp_sipser", g, s.cmatch, s.rmatch))(sharded_g,
+                                               empty_like_graph(sharded_g))
+    np.testing.assert_array_equal(np.asarray(cm), np.asarray(warm.cmatch))
+    np.testing.assert_array_equal(np.asarray(rm), np.asarray(warm.rmatch))
+    assert int(rounds) == int(warm.warm_rounds) > 2, (rounds, warm.warm_rounds)
+    st = ShardedMatcher(mesh, config=MatcherConfig(),
+                        warm_start="karp_sipser").run(sharded_g)
+    single = one.run(graph)
+    assert int(st.warm_rounds) == int(single.warm_rounds) \
+        == int(warm.warm_rounds)
+    np.testing.assert_array_equal(np.asarray(st.cmatch),
+                                  np.asarray(single.cmatch))
+    assert validate_matching(g, *st.to_host()) == maximum_cardinality(g)
+print("DIST_OK")
+"""
+
 SCENARIOS = {"equality": EQUALITY, "cache": CACHE, "pallas": PALLAS,
-             "dirop": DIROP, "compat": COMPAT, "merge": MERGE}
+             "dirop": DIROP, "compat": COMPAT, "merge": MERGE,
+             "warm_rounds": WARM_ROUNDS}
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))

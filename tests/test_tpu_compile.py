@@ -17,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -31,6 +32,8 @@ from repro.serving import ladder
 N = 1 << 20                 # vertices per side
 NNZ = 1 << 23               # edges
 EDGE_BYTES = 2 * NNZ * 4    # ecol + cadj, int32
+SHARDED_N = 1 << 21         # the four-chip cell: vertices per side
+SHARDED_NNZ = 1 << 24       # and edge slots
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +93,64 @@ def _edge_gathers(hlo: str, scope: str) -> int:
                for c, op in gathers)
 
 
+def _computations(hlo: str):
+    """``{computation: [instruction lines]}`` and ``{instruction: type}``
+    of compiled HLO text (instruction names are unique in a module)."""
+    comps, types, comp = {}, {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            comps[comp] = []
+            continue
+        inst = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (\S+)", line)
+        if inst and comp is not None:
+            types[inst.group(1)] = inst.group(2)
+            comps[comp].append(line)
+    return comps, types
+
+
+def _elements(type_: str) -> int:
+    dims = re.match(r"\w+\[([\d,]*)\]", type_).group(1)
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def _round_streams(hlo: str, scope: str):
+    """For each while loop under ``scope``: the element counts of the
+    tables its body gathers edge-length results from, and of the operands
+    it scatters edge-length updates into (nested computations included)."""
+    comps, types = _computations(hlo)
+    out = []
+    for line in (l for lines in comps.values() for l in lines):
+        if " while(" not in line or f"/{scope}/" not in line:
+            continue
+        todo, seen = [re.search(r"body=%([\w.-]+)", line).group(1)], set()
+        while todo:
+            c = todo.pop()
+            if c in seen or c not in comps:
+                continue
+            seen.add(c)
+            for l in comps[c]:
+                todo += re.findall(r"(?:calls|body|condition|to_apply)=%"
+                                   r"([\w.-]+)", l)
+                branches = re.search(r"branch_computations=\{([^}]*)\}", l)
+                if branches:
+                    todo += re.findall(r"%([\w.-]+)", branches.group(1))
+        gathers, scatters = [], []
+        for l in (l for c in seen for l in comps[c]):
+            m = re.match(r"\s*(?:ROOT )?%[\w.-]+ = (\S+) (gather|scatter)\("
+                         r"([^)]*)\)", l)
+            if not m:
+                continue
+            ops = re.findall(r"%([\w.-]+)", m.group(3))
+            if m.group(2) == "gather" and _elements(m.group(1)) == NNZ:
+                gathers.append(_elements(types[ops[0]]))
+            if m.group(2) == "scatter" and _elements(types[ops[-1]]) == NNZ:
+                scatters.append(_elements(types[ops[0]]))
+        out.append((sorted(gathers), sorted(scatters)))
+    return out
+
+
 def _state(graph, sharding):
     shapes = jax.eval_shape(lambda: empty_like_graph(graph))
     return jax.tree.map(
@@ -117,6 +178,21 @@ def test_matcher_run_compiles_for_v5e(one_chip, cfg):
     assert _edge_gathers(compiled.as_text(), "bfs_level") == 1
 
 
+def test_warm_start_rounds_index_the_edges_by_row_only(one_chip):
+    """Each Karp-Sipser degree round and each cheap round of the one-chip
+    program streams the edge list through one gather by ``cadj`` (a table
+    of nr+1 rows) and at most one scatter by ``cadj``; the column side is
+    segment scans, so nothing of edge length is indexed by ``ecol`` (a
+    table of nc+1 columns).  nc != nr tells the two apart."""
+    nc, nr = 1 << 18, 3 << 16
+    g = _graph(nc, nr, NNZ, one_chip)
+    hlo = (Matcher(MatcherConfig(), warm_start="karp_sipser").program(g)
+           .lower(g, _state(g, one_chip)).compile().as_text())
+    rounds = _round_streams(hlo, "warm_start")
+    # the degree rounds' loop, then the cheap rounds' loop
+    assert sorted(rounds) == [([nr + 1], []), ([nr + 1], [nr + 1])], rounds
+
+
 def test_match_many_compiles_for_v5e_at_a_serving_bucket(one_chip):
     bucket = ladder()[-1]
     batch = 8
@@ -137,10 +213,14 @@ def test_sharded_matcher_spreads_edges_over_four_v5e_chips(topo):
     st = _state(g, rep)
     mem = sm.program(g).lower(g, st).compile().memory_analysis()
     # per device: a quarter of the edges plus the replicated O(n) vectors
-    # (cxadj, cmatch, rmatch), never the whole edge list
+    # (cxadj, cmatch, rmatch), never the whole edge list; the scalar
+    # arguments (the state's counters, the edge count) take up to 4 KiB
+    # each
     replicated = 3 * (N + 1) * 4
+    scalars = 5 * 4096
     assert EDGE_BYTES // 4 <= mem.argument_size_in_bytes, mem
-    assert mem.argument_size_in_bytes <= EDGE_BYTES // 4 + replicated + 4096, mem
+    assert mem.argument_size_in_bytes <= (EDGE_BYTES // 4 + replicated
+                                          + scalars), mem
 
 
 def _merge_operands(hlo: str):
@@ -160,17 +240,23 @@ def _merge_operands(hlo: str):
     return [[types[op] for op in ops] for ops in merges]
 
 
-def test_sharded_cell_merges_one_winner_vector_per_level(topo, one_chip):
-    """The four-chip benchmark cell's program (Karp-Sipser, 2^21 per side,
-    2^24 edge slots over a v5e 2x2): the merge of each BFS level is an
-    all-reduce of the (nr+1) int32 winner vector; the one-chip program
-    has no merge at all."""
-    n, nnz = 1 << 21, 1 << 24
+@pytest.fixture(scope="module")
+def sharded_cell_hlo(topo):
+    """The four-chip benchmark cell's compiled program (Karp-Sipser, 2^21
+    per side, 2^24 edge slots over a v5e 2x2), as HLO text."""
     mesh = jax.make_mesh((4,), ("data",), devices=topo.devices)
     sm = ShardedMatcher(mesh, config=MatcherConfig(), warm_start="karp_sipser")
     rep = NamedSharding(sm.mesh, P())
-    g = _graph(n, n, nnz, rep, edge_sharding=NamedSharding(sm.mesh, P("data")))
-    hlo = sm.program(g).lower(g, _state(g, rep)).compile().as_text()
+    g = _graph(SHARDED_N, SHARDED_N, SHARDED_NNZ, rep,
+               edge_sharding=NamedSharding(sm.mesh, P("data")))
+    return sm.program(g).lower(g, _state(g, rep)).compile().as_text()
+
+
+def test_sharded_cell_merges_one_winner_vector_per_level(sharded_cell_hlo,
+                                                         one_chip):
+    """The merge of each BFS level is an all-reduce of the (nr+1) int32
+    winner vector; the one-chip program has no merge at all."""
+    n, hlo = SHARDED_N, sharded_cell_hlo
     operands = _merge_operands(hlo)
     assert operands, "no all-reduce under merge_shards"
     for types in operands:
@@ -200,3 +286,23 @@ def test_pallas_kernels_still_rejected_by_the_v5e_compiler(one_chip, kernel,
     with pytest.raises(NotImplementedError, match=re.escape(reason)):
         sweep.lower(leaf((nnz,)), leaf((nnz,)), state,
                     state if wr else None, state, leaf(())).compile()
+
+
+def test_sharded_warm_start_moves_no_edge_array(sharded_cell_hlo):
+    """GSPMD partitions the warm start's segment scans and its gather and
+    scatter by row over the edge shards in place: no collective of the
+    program, the warm start's among them, moves more than an O(n) vector
+    (the all-reduces of the row and column tables, and the carries of a
+    few elements between the shards' blocks), so none gathers an edge
+    array."""
+    moved = []
+    for line in sharded_cell_hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.-]+ = (.*?) ((?:all-gather|all-reduce|"
+                     r"collective-permute|all-to-all|reduce-scatter)"
+                     r"(?:-start)?)\(", line)
+        if m:
+            moved.append((m.group(2), "/warm_start/" in line, max(
+                [_elements(t) for t in re.findall(r"[su]32\[[\d,]*\]",
+                                                  m.group(1))] or [0])))
+    assert [op for op, ws, _ in moved if ws], "no collective under warm_start"
+    assert max(size for _, _, size in moved) <= SHARDED_N + 2, moved
