@@ -21,7 +21,7 @@ import numpy as np
 from repro.graphs import random_bipartite
 from repro.matching import DeviceCSR, Matcher, MatcherConfig
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

@@ -36,7 +36,7 @@ def instrumented_phases(g, algo: str, max_phases: int = 10_000):
         while True:
             bfs, root, pred, rmatch, ins, aug_l = _expand_level(
                 ecol, cadj, bfs, root, pred, rmatch, level, wr=True,
-                wr_exact=False, use_pallas=False, block_edges=4096)
+                wr_exact=False)
             nlev += 1
             aug = aug or bool(aug_l)
             level = level + 1

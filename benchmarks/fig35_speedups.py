@@ -9,7 +9,7 @@ import numpy as np
 from repro.core import MatcherConfig
 from .common import geomean, prepared_instances, time_matcher, time_sequential
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

@@ -5,9 +5,8 @@ measure).  Run at --scale small for meaningful times:
 
 Covers: (1) paper-faithful variant baselines, (2) the beyond-paper
 bounded-tail APFB sweep (interpolating APsB <-> APFB), (3) level/phase work
-accounting that explains the wins, (4) the frontier-sweep execution paths
-(fused Pallas kernel, legacy two-step Pallas, frontier-adaptive dispatch —
-the per-level microbench behind these lives in benchmarks/perf_smoke.py).
+accounting that explains the wins (the per-level microbench lives in
+benchmarks/perf_smoke.py).
 """
 from __future__ import annotations
 
@@ -39,16 +38,6 @@ def run(scale: str = "small") -> List[str]:
                                                 tail_levels=2)),
             ("apfb-plain tail=4", MatcherConfig(algo="apfb", kernel="gpubfs",
                                                 tail_levels=4)),
-            ("apfb-wr pallas-fused", MatcherConfig(algo="apfb",
-                                                   kernel="gpubfs_wr",
-                                                   use_pallas=True)),
-            ("apfb-wr pallas-legacy", MatcherConfig(algo="apfb",
-                                                    kernel="gpubfs_wr",
-                                                    use_pallas=True,
-                                                    pallas_fused=False)),
-            ("apfb-wr adaptive", MatcherConfig(algo="apfb",
-                                               kernel="gpubfs_wr",
-                                               adaptive_frontier=True)),
         ]
         for cname, cfg in cases:
             times, phases = [], 0

@@ -4,20 +4,14 @@ committed perf baseline (``BENCH_PR7.json``).
 Every row carries a machine-portable ``rel`` ratio (path time over the jnp
 path's time on the same input) so the CI regression gate compares relative
 numbers rather than absolute wall-clock across hosts; the gate reads the
-``sweep_summary`` (geomean over graphs) and ``solve`` sets — per-graph
-sub-millisecond detail rows are for humans, too noisy to gate on.  Row sets:
+``solve`` set — per-graph sub-millisecond sweep rows are for humans, too
+noisy to gate on.  Row sets:
 
 * ``perf_smoke.sweep`` — ONE BFS level of frontier expansion (the O(nnz) hot
-  loop of Figs. 2-5) through each winner path: ``jnp`` (proposals + XLA
-  scatter), ``pallas_legacy`` (proposal kernel + XLA scatter),
-  ``pallas_fused`` (in-kernel winner merge) and ``pallas_pull`` (the
-  direction-optimizing pull kernel streaming the CSC mirror).  On CPU hosts
-  the Pallas paths run through the interpreter (``mode=interpret``); on
-  accelerator backends the same rows carry ``mode=compiled`` — the fused
-  compiled path is the one the paper's speedup story rests on.
+  loop of Figs. 2-5) through the jnp per-row winner oracle
+  (:func:`repro.matching.sweep_ref.frontier_expand_fused_ref`), jitted.
 * ``perf_smoke.solve`` — full ``Matcher.run`` geomeans per sweep config
-  (includes the beyond-paper ``adaptive_frontier`` and ``dirop``
-  dispatches).
+  (the push sweep and the beyond-paper ``dirop`` dispatch).
 
 Run directly, or through the harness + regression gate (refresh the
 committed baseline with ``--update-baseline``, never by hand):
@@ -29,7 +23,6 @@ committed baseline with ``--update-baseline``, never by hand):
 """
 from __future__ import annotations
 
-import functools
 import sys
 from typing import List
 
@@ -38,14 +31,8 @@ import jax.numpy as jnp
 
 from repro.core import MatcherConfig, cheap_matching_jax
 from repro.graphs import random_bipartite, scaled_free
-from repro.kernels.frontier_expand import (frontier_expand,
-                                           frontier_expand_fused,
-                                           frontier_expand_fused_ref,
-                                           frontier_expand_pull,
-                                           resolve_interpret)
-from repro.matching.device_csr import DeviceCSR
-from repro.matching.solve import (IINF, default_block_edges, level0_state,
-                                  scatter_min)
+from repro.matching.solve import level0_state
+from repro.matching.sweep_ref import frontier_expand_fused_ref
 from .common import geomean, time_call, time_matcher
 
 _SCALES = {
@@ -69,60 +56,18 @@ def _sweep_state(g):
     return jnp.asarray(g.ecol), jnp.asarray(g.cadj), bfs, root, rmj
 
 
-def _csc_arrays(g):
-    """The row-sorted (radj, erow) mirror the pull kernel streams."""
-    d = DeviceCSR.from_host(g).with_csc()
-    return d.radj, d.erow
-
-
-# the rel denominator: the SAME proposals + per-row min-merge oracle the
-# kernels are tested against, jitted — reimplementing the formula here
-# would let the committed baseline drift from the solver's real jnp path
+# the same proposals + per-row min-merge oracle the solver's sweeps are
+# tested against, jitted
 _jnp_winner = jax.jit(frontier_expand_fused_ref)
-
-
-def _sweep_paths(interpret: bool):
-    """path name -> winner fn(ecol, cadj, bfs, root, rmj, blk).
-
-    Each path is ONE jitted dispatch (the legacy kernel + its XLA merge are
-    jitted together), so rel ratios measure the sweeps, not eager-dispatch
-    overhead one competitor happens to pay.
-    """
-    @functools.partial(jax.jit, static_argnames=("blk",))
-    def legacy(ecol, cadj, bfs, root, rmj, *, blk):
-        nr = rmj.shape[0] - 1
-        prop = frontier_expand(ecol, cadj, bfs, root, rmj, 2,
-                               block_edges=blk, interpret=interpret)
-        return scatter_min(nr, jnp.where(prop < IINF, cadj, nr), prop)
-
-    @functools.partial(jax.jit, static_argnames=("blk",))
-    def fused(ecol, cadj, bfs, root, rmj, *, blk):
-        return frontier_expand_fused(ecol, cadj, bfs, root, rmj, 2,
-                                     block_edges=blk, interpret=interpret)
-
-    @functools.partial(jax.jit, static_argnames=("blk",))
-    def pull(radj, erow, bfs, root, rmj, *, blk):
-        # same winner contract, CSC edge stream (row-sorted tiles whose
-        # merge skips when the tile proposes nothing)
-        return frontier_expand_pull(radj, erow, bfs, root, rmj, 2,
-                                    block_edges=blk, interpret=interpret)
-
-    return {"pallas_legacy": legacy, "pallas_fused": fused,
-            "pallas_pull": pull}
 
 
 def run(scale: str = "tiny") -> List[str]:
     backend = jax.default_backend()
-    interpret = resolve_interpret(None)
-    mode = "interpret" if interpret else "compiled"
     rows = ["perf_smoke.sweep,backend,mode,graph,path,block_edges,ms,rel"]
-    reps = 20                       # sweeps per timed call: sub-ms kernels
-    rels = {}                       # would make the rel gate flaky
+    reps = 20                       # sweeps per timed call: sub-ms sweeps
     for gname, build in _SCALES[scale]:
         g = build()
         ecol, cadj, bfs, root, rmj = _sweep_state(g)
-        radj, erow = _csc_arrays(g)
-        blk = default_block_edges(int(ecol.shape[0]), "ct")
 
         def timed(fn):
             fn()                    # compile (not timed)
@@ -136,29 +81,10 @@ def run(scale: str = "tiny") -> List[str]:
                                          jnp.int32(2)))
         rows.append(f"perf_smoke.sweep,{backend},xla,{gname},jnp,-,"
                     f"{base*1e3:.3f},1.000")
-        for pname, fn in _sweep_paths(interpret).items():
-            ea, eb = (radj, erow) if pname == "pallas_pull" else (ecol, cadj)
-            t = timed(lambda: fn(ea, eb, bfs, root, rmj, blk=blk))
-            rows.append(f"perf_smoke.sweep,{backend},{mode},{gname},{pname},"
-                        f"{blk},{t*1e3:.3f},{t/base:.3f}")
-            rels.setdefault(pname, []).append(t / base)
-
-    # the gate rows: geomean over graphs is far less noisy than any one
-    # sub-ms measurement (benchmarks/run.py GATED_SETS)
-    rows.append("perf_smoke.sweep_summary,backend,mode,path,rel")
-    for pname, rs in rels.items():
-        rows.append(f"perf_smoke.sweep_summary,{backend},{mode},{pname},"
-                    f"{geomean(rs):.3f}")
 
     rows.append("perf_smoke.solve,backend,mode,config,geomean_ms,rel")
     solve_cases = [
         ("jnp", MatcherConfig(algo="apfb", kernel="gpubfs_wr")),
-        ("pallas_fused", MatcherConfig(algo="apfb", kernel="gpubfs_wr",
-                                       use_pallas=True)),
-        ("pallas_legacy", MatcherConfig(algo="apfb", kernel="gpubfs_wr",
-                                        use_pallas=True, pallas_fused=False)),
-        ("adaptive", MatcherConfig(algo="apfb", kernel="gpubfs_wr",
-                                   adaptive_frontier=True)),
         ("dirop", MatcherConfig(algo="apfb", kernel="gpubfs_wr",
                                 dirop=True)),
     ]
@@ -171,8 +97,7 @@ def run(scale: str = "tiny") -> List[str]:
         ms = geomean(times) * 1e3
         if base_ms is None:
             base_ms = ms
-        m = "xla" if not cfg.use_pallas else mode
-        rows.append(f"perf_smoke.solve,{backend},{m},{cname},{ms:.2f},"
+        rows.append(f"perf_smoke.solve,{backend},xla,{cname},{ms:.2f},"
                     f"{ms/base_ms:.3f}")
     return rows
 

@@ -16,8 +16,8 @@ with the same identity, and the harness exits non-zero on a
 gating against one: the gated benches re-run ``--runs`` times and each gated
 row's ``rel`` is written as the **max envelope** over the runs (the same
 discipline the earlier hand-assembled artifacts followed, now mechanical —
-never hand-edit a baseline again).  ``--list`` prints the registered benches
-(including ``autotune``, so block-size sweeps run through this harness too).
+never hand-edit a baseline again).  ``--list`` prints the registered
+benches.
 """
 from __future__ import annotations
 
@@ -29,10 +29,9 @@ import time
 
 from repro.matching import enable_persistent_compile_cache
 
-from . import (autotune, batch_matching, corpus, fig2_bfs_iters,
-               fig35_speedups, perf_matcher, perf_smoke, roofline, serving,
-               sharded_matching, table1_variants, table2_hardest, table_init,
-               table_router)
+from . import (batch_matching, corpus, fig2_bfs_iters, fig35_speedups,
+               perf_matcher, perf_smoke, roofline, serving, sharded_matching,
+               table1_variants, table2_hardest, table_init, table_router)
 
 BENCHES = {
     "table1": table1_variants.run,     # paper Table 1
@@ -43,7 +42,6 @@ BENCHES = {
     "init": table_init.run,            # KS vs cheap init (beyond-paper)
     "perf_matcher": perf_matcher.run,  # matcher hillclimb (docs/architecture.md)
     "perf_smoke": perf_smoke.run,      # level-sweep microbench (perf gate)
-    "autotune": autotune.run,          # fused-kernel block_edges sweep
     "roofline": roofline.run,          # roofline table (from dry-run artifacts)
     "batch": batch_matching.run,       # match_many serving throughput
     "sharded": sharded_matching.run,   # ShardedMatcher vs single-device sweep
@@ -63,8 +61,8 @@ BENCHES = {
 # saturation as rel); it stays dormant against baselines that predate it
 # (no matching rows -> skipped) until the baseline artifact is refreshed.
 REGRESSION_BENCHES = ("perf_smoke", "corpus", "serving")
-GATED_SETS = ("perf_smoke.sweep_summary", "perf_smoke.solve",
-              "corpus.heuristic", "serving.overload_summary")
+GATED_SETS = ("perf_smoke.solve", "corpus.heuristic",
+              "serving.overload_summary")
 
 SCHEMA = "repro-bench/1"
 

@@ -32,7 +32,7 @@ from repro.matching.device_csr import bucket_nnz
 from repro.serving import (Bucketizer, MatchingService, QueueFullError,
                            SizeBucket, percentile)
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

@@ -28,7 +28,7 @@ from repro.matching import (DeviceCSR, Matcher, MatcherConfig,  # noqa: E402
 
 from .common import time_call  # noqa: E402
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

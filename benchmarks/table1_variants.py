@@ -1,5 +1,6 @@
-"""Paper Table 1: geometric-mean runtime of the eight matcher variants
-(APFB/APsB x GPUBFS/GPUBFS-WR x MT/CT) on the original and RCP sets."""
+"""Paper Table 1: geometric-mean runtime of the matcher variants
+(APFB/APsB x GPUBFS/GPUBFS-WR; the paper's MT/CT thread-grid axis has no
+counterpart in the XLA sweep) on the original and RCP sets."""
 from __future__ import annotations
 
 from typing import List

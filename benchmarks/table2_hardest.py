@@ -8,7 +8,7 @@ from typing import List
 from repro.core import MatcherConfig
 from .common import prepared_instances, time_matcher, time_sequential
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

@@ -12,7 +12,7 @@ from repro.core import maximum_cardinality
 from repro.graphs import instance_sets
 from repro.matching import DeviceCSR, Matcher, MatcherConfig
 
-BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+BEST = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 
 
 def run(scale: str = "tiny") -> List[str]:

@@ -106,16 +106,6 @@ def check_levels(state, what: str) -> int:
     return levels
 
 
-def check_config(matcher) -> None:
-    """The chip runs the XLA sweep, compiled: never the Pallas
-    interpreter (the CPU rehearsal is the one place it is on)."""
-    cfg = matcher.config
-    if cfg.use_pallas or cfg.pallas_interpret != (PLATFORM == "cpu"):
-        raise AssertionError(f"unexpected sweep path: use_pallas="
-                             f"{cfg.use_pallas} pallas_interpret="
-                             f"{cfg.pallas_interpret}")
-
-
 def phase_single() -> None:
     from repro.core import maximum_cardinality
     from repro.graphs import kron_graph, random_bipartite
@@ -131,14 +121,12 @@ def phase_single() -> None:
     for name, cfg in runs:
         g = graphs[name]
         matcher = Matcher(cfg, warm_start="karp_sipser")
-        check_config(matcher)
         graph = DeviceCSR.from_host(g)
         state, timing = timed_run(lambda: matcher.run(graph))
         card = check_matching(g, *state.to_host(), oracle[name], name)
         levels = check_levels(state, name)
         log(f"[single] {name} {g.nc}x{g.nr} nnz={g.nnz} "
-            f"{matcher.config.name}+karp_sipser pallas_interpret="
-            f"{matcher.config.pallas_interpret} {timing} "
+            f"{matcher.config.name}+karp_sipser {timing} "
             f"phases={int(state.phases)} levels={levels} |M|={card} "
             f"oracle={oracle[name]}")
 
@@ -155,7 +143,6 @@ def phase_batch() -> None:
         DeviceCSR.from_host(g).pad_vertices(BATCH_N, BATCH_N).pad_to(cap)
         for _, g in trace])
     matcher = Matcher(MatcherConfig(), warm_start="cheap")
-    check_config(matcher)
     states, timing = timed_run(lambda: matcher.run_many(batch))
     cms, rms = states.to_host()
     cards = []
@@ -165,8 +152,7 @@ def phase_batch() -> None:
                                     f"batch lane {i} ({family})"))
     log(f"[batch] {BATCH_SIZE} x ({BATCH_N}x{BATCH_N}, nnz_pad={cap}) "
         f"families={[f for f, _ in trace]} {matcher.config.name}+cheap "
-        f"pallas_interpret={matcher.config.pallas_interpret} {timing} "
-        f"|M|={cards} (all equal to the oracle)")
+        f"{timing} |M|={cards} (all equal to the oracle)")
 
 
 def phase_service() -> None:
@@ -182,14 +168,12 @@ def phase_service() -> None:
         max_delay_ms=2.0)
     try:
         matcher = service.matcher()
-        check_config(matcher)
         ev0 = dict(_EVENTS)
         report = service.warm_up()
         ev = {k: _EVENTS[k] - ev0[k] for k in _EVENTS}
         log(f"[service] {len(service.bucketizer.buckets)} buckets "
-            f"{matcher.config.name}+{service.warm_start} pallas_interpret="
-            f"{matcher.config.pallas_interpret} {report}; compile_s="
-            f"{ev['compile_s']:.3f} persistent_cache(requests="
+            f"{matcher.config.name}+{service.warm_start} {report}; "
+            f"compile_s={ev['compile_s']:.3f} persistent_cache(requests="
             f"{ev['requests']} hits={ev['hits']} writes={ev['writes']})")
         trace = build_trace(SERVICE_REQUESTS, SERVICE_SIZE, SEED)
         futures = replay(service, trace, rate_rps=300.0, seed=SEED)
@@ -256,7 +240,6 @@ def phase_sharded(n_devices: int) -> None:
     # the longest step, and its cardinality is what every warm start must hit
     ref_ws = "karp_sipser"
     single = Matcher(MatcherConfig(), warm_start=ref_ws)
-    check_config(single)
     single_graph = DeviceCSR.from_host(g, device=jax.devices()[0])
     ref, timing = timed_run(lambda: single.run(single_graph), steady=False)
     ref_card = check_matching(g, *ref.to_host(), opt, "single-chip")
@@ -265,7 +248,6 @@ def phase_sharded(n_devices: int) -> None:
         f"|M|={ref_card} oracle={opt}")
     for ws in (ref_ws, "cheap", "none"):
         sm = ShardedMatcher(mesh, config=MatcherConfig(), warm_start=ws)
-        check_config(sm)
         st, timing = timed_run(lambda: sm.run(sharded), steady=False)
         card = check_matching(g, *st.to_host(), opt, f"sharded {ws}")
         same = ""

@@ -22,7 +22,7 @@ from repro.serving import Bucketizer, MatchingService, SizeBucket          # noq
 
 
 def main():
-    cfg = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+    cfg = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
     service = MatchingService(
         bucketizer=Bucketizer((SizeBucket(256, 256, 2048),),

@@ -2,7 +2,7 @@
 
 The distributed edge-partitioned matcher (the paper's stated future work)
 lives in :mod:`repro.matching.sharded` and shares the APFB/APsB solve loop,
-warm-start registry, compile cache and Pallas frontier kernel with the
+warm-start registry, compile cache and XLA frontier sweep with the
 single-device :class:`repro.matching.Matcher` — see ``docs/architecture.md``
 (design + per-level collective cost) and ``docs/paper_map.md``.  This module
 keeps only the original host-centric entry point (numpy in / numpy out,

@@ -4,8 +4,8 @@ The solver itself (the paper's APFB/APsB drivers, GPUBFS/GPUBFS-WR expansion,
 ALTERNATE + FIXMATCHING) lives in :mod:`repro.matching.solve` as pure
 shape-polymorphic JAX; this module keeps the original host-centric entry
 point :func:`maximum_matching` (numpy in / numpy out, stats as a dict) and
-re-exports the kernel internals for the instrumented benchmarks, the
-distributed matcher and the Pallas kernel tests.
+re-exports the solver internals for the instrumented benchmarks and the
+distributed matcher.
 
 New code should use :class:`repro.matching.Matcher` directly — it keeps
 graphs and matcher state on device and composes under ``jit``/``vmap``.
@@ -20,8 +20,7 @@ from repro.matching.config import MatcherConfig, VARIANTS           # noqa: F401
 from repro.matching.solve import (FOUND, IINF, L0, NEG, UNVISITED,   # noqa: F401
                                   _alternate, _cardinality,
                                   _expand_level, _fix_matching,
-                                  default_block_edges, make_solver,
-                                  scatter_min)
+                                  make_solver, scatter_min)
 from repro.matching.api import Matcher
 from repro.matching.device_csr import DeviceCSR
 from repro.matching.state import MatchState

@@ -13,10 +13,14 @@ fixed work model:
   ``cost = ntiles * LANE`` (= the padded edge count);
 * a pull level pays ``PULL_TILE_OVERHEAD`` lanes per CSC tile (the stream +
   skip decision) and full ``LANE`` cost only for tiles that actually
-  contain an unreached row's edge — the tile-skip win of the streaming
-  ``frontier_expand_pull`` kernel, which is large when the remaining rows
-  are clustered (late levels, road/comb-like instances) and small when RCP
-  permutation scatters them.
+  contain an unreached row's edge — the tile-skip win of a streaming pull
+  sweep, which is large when the remaining rows are clustered (late
+  levels, road/comb-like instances) and small when RCP permutation
+  scatters them.  Neither pull sweep the solver has skips tiles (the
+  compact row-gather costs O(cap * dmax), the sharded pull is the XLA
+  sweep), so the model prices a sweep the repository no longer runs: a
+  recorded debt (ROADMAP.md), to be settled against chip measurements of
+  ``dirop`` on a long-diameter cell.
 
 ``modelled_rel`` = dirop cost / push-only cost is then a pure function of
 (instance, warm start, alpha, beta): deterministic, portable across
@@ -54,11 +58,9 @@ PULL_TILE_OVERHEAD = 16
 # tile constants directly
 PULL_STREAM_FRACTION = PULL_TILE_OVERHEAD / LANE
 
-# the replay's dense expansion step: the solver's own _expand_level with the
-# default-variant statics (APFB / gpubfs_wr / jnp sweep).  block_edges is a
-# Pallas-only knob, inert on the jnp path.
-_STEP = jax.jit(functools.partial(_expand_level, wr=True, wr_exact=False,
-                                  use_pallas=False, block_edges=128))
+# the replay's push expansion step: the solver's own _expand_level with the
+# default-variant statics (APFB / gpubfs_wr).
+_STEP = jax.jit(functools.partial(_expand_level, wr=True, wr_exact=False))
 
 _BASE = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 

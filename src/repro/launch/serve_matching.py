@@ -154,7 +154,7 @@ def main(argv=None) -> int:
         bucketizer=Bucketizer(buckets,
                               oversize="shard" if mesh else "reject",
                               validate=True),
-        config=MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct"),
+        config=MatcherConfig(algo="apfb", kernel="gpubfs_wr"),
         warm_start="cheap", max_batch=args.max_batch,
         max_delay_ms=args.delay_ms, mesh=mesh, faults=injector)
     report = service.warm_up()

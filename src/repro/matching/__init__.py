@@ -4,7 +4,7 @@ The public surface of the reproduction:
 
 * :class:`DeviceCSR` — pytree bipartite graph (column-major CSR + the
   edge-parallel view) that passes straight through ``jax.jit`` / ``jax.vmap``;
-* :class:`MatcherConfig` — one of the paper's eight variants;
+* :class:`MatcherConfig` — one of the paper's four variants;
 * :class:`Matcher` — facade whose :meth:`Matcher.run` composes a registered
   warm start (``"none" | "cheap" | "karp_sipser"``) with the APFB/APsB solver
   in ONE compiled program (no host hop between init and solve);
@@ -25,7 +25,7 @@ The public surface of the reproduction:
 package.  ``docs/architecture.md`` documents the design; ``docs/paper_map.md``
 maps every paper algorithm to its implementation here.
 """
-from .config import MatcherConfig, PallasUnsupportedError, VARIANTS
+from .config import MatcherConfig, VARIANTS
 from .device_csr import DeviceCSR, GraphValidationError, validate_structure
 from .state import MatchState, MatchStats
 from .warmstart import WARM_STARTS, register_warm_start, warm_start_names
@@ -38,7 +38,7 @@ from .cache import (compile_cache_clear, compile_cache_info,
                     get_compiled)
 
 __all__ = [
-    "MatcherConfig", "PallasUnsupportedError", "VARIANTS",
+    "MatcherConfig", "VARIANTS",
     "DeviceCSR", "GraphValidationError", "validate_structure",
     "MatchState", "MatchStats",
     "Matcher", "match_many", "maximum_matching_device",

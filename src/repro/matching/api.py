@@ -33,11 +33,7 @@ class Matcher:
 
     def __init__(self, config: MatcherConfig = MatcherConfig(),
                  warm_start: str = "none"):
-        # canonical(): the pallas_interpret=None auto marker resolves to the
-        # backend's concrete compilation mode here, so every compile-cache
-        # key built from self.config carries the real interpret bool; it
-        # also refuses compiled Pallas kernels here, before any tracing.
-        self.config = config.canonical()
+        self.config = config
         self.warm_start = warm_start
         get_warm_start(warm_start)      # fail fast on unknown names
 
@@ -77,9 +73,8 @@ class Matcher:
         """Run the solver from ``state`` (pure; no warm start applied)."""
         self._check_state(graph, state)
         kw = {}
-        if self.config.adaptive_frontier or self.config.dirop:
-            kw["cxadj"] = graph.cxadj
         if self.config.dirop:
+            kw["cxadj"] = graph.cxadj
             if not graph.has_csc:
                 raise ValueError(
                     "MatcherConfig(dirop=True) needs the CSC mirror; build "
@@ -149,16 +144,6 @@ class Matcher:
         One ``vmap``-compiled program solves the whole batch per dispatch —
         the serving path for many concurrent matching requests.
         """
-        if self.config.adaptive_frontier:
-            # vmap turns the per-level lax.cond into a select: every graph
-            # would run BOTH the dense and the compact sweep each level — a
-            # strict pessimization, so refuse rather than quietly regress.
-            # (dirop is allowed through: the serving layer batches dirop
-            # requests and correctness is unaffected, but the same
-            # cond->select cost applies — see docs/architecture.md.)
-            raise ValueError(
-                "adaptive_frontier composes with per-graph run() only; "
-                "under run_many's vmap both sweeps would execute each level")
         assert graphs.batch_shape, "run_many expects a stacked DeviceCSR"
         cold = states is None
         if cold:

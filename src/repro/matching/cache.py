@@ -85,18 +85,12 @@ def compile_cache_key(bucket_key: Tuple[int, ...], cfg, warm_start: str,
                       entry: str) -> Hashable:
     """Canonical key: (bucket shape, config, warm start, entry point).
 
-    ``cfg`` must be the *canonical* MatcherConfig (``MatcherConfig.
-    canonical()`` — ``Matcher.__init__`` applies it): the Pallas
-    ``pallas_interpret=None`` auto marker is resolved to the backend's
-    concrete compilation mode first, so a program compiled in interpret mode
-    can never be served where a compiled kernel was requested (and the other
-    way around), and every execution-path knob (``use_pallas``,
-    ``pallas_fused``, ``pallas_block_edges``, ``adaptive_frontier``,
-    ``dirop`` + its heuristic/geometry fields, ...) lands in the key by
-    being part of the frozen dataclass.  ``bucket_key`` additionally carries
-    the CSC-mirror marker (``DeviceCSR.bucket_key`` appends ``"csc"``), so a
-    mirrored graph — different pytree leaves, different traced program —
-    never shares an entry with a bare one.
+    ``cfg`` is the :class:`~repro.matching.MatcherConfig` itself: every
+    execution-path knob (``dirop`` + its heuristic/geometry fields, ...)
+    lands in the key by being part of the frozen dataclass.  ``bucket_key``
+    additionally carries the CSC-mirror marker (``DeviceCSR.bucket_key``
+    appends ``"csc"``), so a mirrored graph — different pytree leaves,
+    different traced program — never shares an entry with a bare one.
     """
     return (bucket_key, cfg, warm_start, entry)
 

@@ -1,41 +1,26 @@
-"""Matcher variant configuration (the paper's eight-variant matrix)."""
+"""Matcher variant configuration (the paper's four-variant matrix)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
-
-
-class PallasUnsupportedError(NotImplementedError):
-    """``use_pallas=True`` asked for compiled Pallas kernels on a backend
-    that cannot compile them (every compiled backend, today: the TPU
-    compiler rejects the frontier kernels, see
-    :data:`repro.kernels.frontier_expand.frontier_expand.MOSAIC_REJECTION`).
-    Raised when a :class:`~repro.matching.Matcher` is built, so the refusal
-    never surfaces inside a traced program and never falls back to the
-    interpreter."""
 
 
 @dataclasses.dataclass(frozen=True)
 class MatcherConfig:
-    """One of the paper's eight variants (2 algos x 2 BFS kernels x 2
-    schedules), plus the frontier-sweep execution knobs.
+    """One of the paper's four variants (2 algos x 2 BFS kernels), plus the
+    solve-loop and frontier-sweep knobs.
 
-    The sweep knobs (``use_pallas`` .. ``pull_dmax``) select *how* the
-    per-level frontier expansion runs; they never change the matching
-    the solver returns — every path is bit-identical to the deterministic
-    min-merge semantics (asserted in tests/test_frontier_paths.py).  All of
-    them are fields of this frozen dataclass, so every one lands in the
-    compile-cache key by construction — there are no untracked execution
-    knobs hiding in kwarg defaults.
+    The sweep knobs (``dirop`` .. ``pull_dmax``) select *how* the per-level
+    frontier expansion runs; they never change the matching the solver
+    returns — every path is bit-identical to the deterministic min-merge
+    semantics (asserted in tests/test_frontier_paths.py).  All of them are
+    fields of this frozen dataclass, so every one lands in the compile-cache
+    key by construction — there are no untracked execution knobs hiding in
+    kwarg defaults.
     """
 
     algo: str = "apfb"          # "apfb" (HKDW-like) | "apsb" (HK-like)
     kernel: str = "gpubfs_wr"   # "gpubfs" | "gpubfs_wr"
-    schedule: str = "ct"        # "ct" | "mt" — edge-tile geometry (Pallas path)
     wr_exact: bool = False      # the APsB-GPUBFS-WR refinement (negative-row encoding)
-    # route frontier expansion through the Pallas kernels: interpret mode
-    # only (the CPU test path); compiled, they are refused by canonical()
-    use_pallas: bool = False
     max_phases: int = 0         # 0 = until maximum (bounded internally)
     # When a positive max_phases budget exhausts before the solver certifies
     # the matching maximum, run one extra greedy augmentation round
@@ -52,32 +37,6 @@ class MatcherConfig:
     # frontier); k>0 on APFB = expand at most k more levels — interpolates
     # between the paper's two drivers (benchmarks/perf_matcher.py).
     tail_levels: int = 0
-    # -- Pallas frontier-sweep geometry -------------------------------------
-    # fused kernel (in-VMEM per-row winner merge, no (nnz,) proposal array);
-    # False = legacy two-step path (proposal kernel + XLA scatter), kept for
-    # benchmarking the fusion win (benchmarks/perf_smoke.py).
-    pallas_fused: bool = True
-    # None = auto: interpret on CPU, compile elsewhere (which canonical()
-    # refuses while use_pallas is on).  Resolved once per Matcher
-    # (``canonical()``) so the concrete bool — not the auto marker — lands
-    # in the compile-cache key.
-    pallas_interpret: Optional[bool] = None
-    # 0 = auto (default_block_edges: CT 4096 / MT 512, clamped to the padded
-    # edge count); >0 = explicit tile size, e.g. from benchmarks/autotune.py.
-    pallas_block_edges: int = 0
-    # -- beyond-paper: frontier-adaptive dispatch (default off) -------------
-    # Track the frontier size each level and switch to a compact
-    # column-gather sweep (O(cap * dmax) instead of O(nnz)) whenever the
-    # frontier fits `compact_cap` columns of degree <= `compact_dmax`;
-    # falls back to the full sweep at runtime otherwise, so results stay
-    # bit-identical.  0 = auto-size to the bucket (resolve_cap/resolve_dmax
-    # below — the ONE definition of the auto geometry; solve.make_solver
-    # resolves per bucket, a pure function of (config, bucket) so the 0
-    # marker in the compile-cache key is unambiguous).  Single-device only
-    # (the sharded path keeps the dense per-shard sweep + one pmin).
-    adaptive_frontier: bool = False
-    compact_cap: int = 0
-    compact_dmax: int = 0
     # -- beyond-paper: direction-optimizing frontier engine (default off) ---
     # Beamer-style push/pull switching per BFS level, in-jit: estimate the
     # frontier's outgoing edges (push work actually useful) against the
@@ -86,25 +45,23 @@ class MatcherConfig:
     #     frontier_edges * dirop_alpha > pull_edges,
     # staying in pull — hysteresis — while
     #     frontier_edges * dirop_beta  > pull_edges   (beta > alpha).
-    # On the jnp path the pull sweep is a compact row-gather of
-    # O(pull_cap * pull_dmax) (0 = auto, same resolution rule as the
-    # compact push geometry but sized on nr) and additionally requires the
-    # unreached rows to fit that geometry; on the Pallas path it is the
-    # streaming `frontier_expand_pull` kernel (row-sorted tiles whose merge
-    # skips when the tile proposes nothing).  Either way the winners are
-    # bit-identical to the push sweeps, so the dispatch never changes the
-    # matching.  Composes with ShardedMatcher (per-shard pull over the CSC
-    # shard, the one per-level pmin unchanged).  Mutually exclusive with
-    # `adaptive_frontier`, which it generalizes.
+    # On one device the pull sweep is a compact row-gather of
+    # O(pull_cap * pull_dmax) (0 = auto, see resolve_cap/resolve_dmax) and
+    # additionally requires the unreached rows to fit that geometry; in
+    # ShardedMatcher it is the XLA sweep streamed over the CSC shard (the
+    # one per-level pmin unchanged).  Either way the winners are
+    # bit-identical to the push sweep, so the dispatch never changes the
+    # matching.
     # The alpha/beta defaults come from the committed corpus sweep
     # (BENCH_PR7.json, ``corpus.alpha_sweep`` / ``_summary`` rows, tiny
     # scale; regenerate via benchmarks/run.py --update-baseline): 8/32 ties
     # the best geomean across the 10-family corpus (0.997 vs push-only) and
-    # is the clear winner on the long-diameter families (grid 0.699) where
-    # pull tile-skipping pays; RCP permutation erases most of that win
-    # (grid_rcp 0.951), which is the paper's locality story.  The per-family
-    # rows are gated in CI (``corpus.heuristic``), so changing these
-    # defaults without refreshing the baseline fails the bench gate.
+    # is the clear winner on the long-diameter families (grid 0.699).  That
+    # sweep prices a pull that skips the edge tiles holding no unreached
+    # row (corpus/heuristic.py's work model), which neither pull sweep here
+    # does; the per-family rows are gated in CI (``corpus.heuristic``), so
+    # changing these defaults without refreshing the baseline fails the
+    # bench gate.
     dirop: bool = False
     dirop_alpha: float = 8.0
     dirop_beta: float = 32.0
@@ -114,67 +71,34 @@ class MatcherConfig:
     def __post_init__(self):
         assert self.algo in ("apfb", "apsb")
         assert self.kernel in ("gpubfs", "gpubfs_wr")
-        assert self.schedule in ("ct", "mt")
         if self.wr_exact:
             assert self.kernel == "gpubfs_wr"
-        assert self.pallas_block_edges >= 0, self.pallas_block_edges
-        assert self.compact_cap >= 0 and self.compact_dmax >= 0, \
-            (self.compact_cap, self.compact_dmax)
         assert self.pull_cap >= 0 and self.pull_dmax >= 0, \
             (self.pull_cap, self.pull_dmax)
         assert self.dirop_alpha > 0 and self.dirop_beta >= self.dirop_alpha, \
             ("hysteresis needs 0 < dirop_alpha <= dirop_beta",
              self.dirop_alpha, self.dirop_beta)
-        if self.dirop and self.adaptive_frontier:
-            raise ValueError(
-                "dirop generalizes adaptive_frontier; enable one, not both")
 
     @staticmethod
     def resolve_cap(auto_or_value: int, n: int) -> int:
-        """The 0 = auto capacity rule for the compact sweeps: n/8 clamped to
-        [64, 1024] (well under any dense O(nnz) sweep).  ``n`` is nc for the
-        push-compact gather, nr for the pull gather."""
+        """The 0 = auto row capacity of the compact pull sweep: n/8 clamped
+        to [64, 1024] (well under any dense O(nnz) sweep), ``n`` = nr."""
         return auto_or_value or max(64, min(1024, n // 8))
 
     @staticmethod
     def resolve_dmax(auto_or_value: int) -> int:
-        """The 0 = auto per-vertex degree bound of the compact sweeps."""
+        """The 0 = auto per-row degree bound of the compact pull sweep."""
         return auto_or_value or 8
 
     @property
     def name(self) -> str:
-        s = f"{self.algo}-{self.kernel}-{self.schedule}"
-        return s + ("-exact" if self.wr_exact else "")
-
-    def canonical(self) -> "MatcherConfig":
-        """Resolve the ``pallas_interpret=None`` auto marker to a concrete
-        bool (interpret only on CPU) so compile-cache keys built from this
-        config always carry the real compilation mode.
-
-        Raises :class:`PallasUnsupportedError` for ``use_pallas=True`` with
-        interpretation off: no installed compiler accepts the kernels, so the
-        solve path on an accelerator is the XLA sweep (``use_pallas=False``).
-        """
-        from repro.kernels.frontier_expand.frontier_expand import (
-            MOSAIC_REJECTION, resolve_interpret)
-        cfg = self
-        if cfg.pallas_interpret is None:
-            cfg = dataclasses.replace(
-                cfg, pallas_interpret=resolve_interpret(None))
-        if cfg.use_pallas and not cfg.pallas_interpret:
-            raise PallasUnsupportedError(
-                "MatcherConfig(use_pallas=True) needs the Pallas interpreter "
-                "(pallas_interpret=True, the CPU test path): the TPU "
-                f"compiler rejects the frontier kernels ({MOSAIC_REJECTION}"
-                " — they gather from O(n) VMEM vectors by edge endpoint). "
-                "Use use_pallas=False, the XLA sweep, on an accelerator.")
-        return cfg
+        return f"{self.algo}-{self.kernel}" + ("-exact" if self.wr_exact
+                                               else "")
 
 
 VARIANTS = tuple(
-    MatcherConfig(algo=a, kernel=k, schedule=s,
+    MatcherConfig(algo=a, kernel=k,
                   wr_exact=(a == "apsb" and k == "gpubfs_wr"))
     for a in ("apfb", "apsb")
     for k in ("gpubfs", "gpubfs_wr")
-    for s in ("ct", "mt")
 )

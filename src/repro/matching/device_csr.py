@@ -346,7 +346,7 @@ class DeviceCSR:
         padded so every shard is itself a canonical power-of-two bucket
         (:func:`bucket_nnz`): the result stays an ordinary ``DeviceCSR``
         pytree whose :attr:`bucket_key` is cacheable, and each per-device
-        slice keeps the lane alignment the Pallas kernel tiles over.
+        slice keeps the 128-lane alignment.
         Padding edges carry sentinel endpoints and are inert, as everywhere;
         they accumulate at the tail, but the per-level sweep is a dense
         vector op over every lane of a shard, so work per device is exactly

@@ -1,14 +1,13 @@
 """Solve-path registry: every end-to-end way this package computes a matching.
 
-The same APFB/APsB solve loop reaches the device through several execution
-paths — plain jnp, the legacy proposal kernel + ``scatter_min`` merge, the
-fused Pallas kernel, the compact adaptive-frontier gather, the
-direction-optimizing engine (jnp and Pallas pull sweeps), and the
+The same APFB/APsB solve loop reaches the device through three execution
+paths — the XLA push sweep (``jnp``), the direction-optimizing engine
+(``dirop``: push or a compact pull over the CSC mirror, per level), and the
 edge-sharded ``shard_map`` program.  All must produce a maximum matching on
-every instance; several must be *bit-identical*.  This registry gives that
-family one enumerable surface so the differential fuzz harness
-(:mod:`repro.corpus.verify`), the parity tests and the benchmarks stop
-hand-rolling their own config lists that drift apart.
+every instance; the single-device ones must be *bit-identical*.  This
+registry gives that family one enumerable surface so the differential fuzz
+harness (:mod:`repro.corpus.verify`), the parity tests and the benchmarks
+stop hand-rolling their own config lists that drift apart.
 
 Each :class:`SolvePath` is a named set of :class:`MatcherConfig` overrides
 plus how to build its matcher; :meth:`SolvePath.run_host` is the
@@ -105,13 +104,9 @@ def solve_path_names() -> Tuple[str, ...]:
     return tuple(SOLVE_PATHS)
 
 
-# the built-in paths — one per frontier-sweep execution strategy.  Geometry
-# knobs (compact_cap / pull_cap / block_edges) stay on auto: their resolution
-# is part of what the differential harness must cover.
+# the built-in paths — one per frontier-sweep execution strategy.  The pull
+# geometry (pull_cap / pull_dmax) stays on auto: its resolution is part of
+# what the differential harness must cover.
 register_solve_path("jnp", {})
-register_solve_path("legacy", dict(use_pallas=True, pallas_fused=False))
-register_solve_path("fused", dict(use_pallas=True, pallas_fused=True))
-register_solve_path("adaptive", dict(adaptive_frontier=True))
 register_solve_path("dirop", dict(dirop=True))
-register_solve_path("dirop_pallas", dict(dirop=True, use_pallas=True))
 register_solve_path("sharded", {}, sharded=True)

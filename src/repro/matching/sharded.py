@@ -12,9 +12,8 @@ Matcher` — :func:`repro.matching.solve.make_solver` with a mesh axis bound:
   scale-out of the paper's CT strided edge ownership;
 * the O(n) BFS state (``bfs``/``root``/``pred``/``cmatch``/``rmatch``) is
   replicated; every level each device sweeps its own edge shard into a local
-  per-row winner vector (the fused Pallas ``frontier_expand_fused`` kernel
-  when ``config.use_pallas`` — each shard's min-merge happens inside its
-  kernel — the jnp sweep + scatter otherwise) and the shard winners merge
+  per-row winner vector (the XLA sweep: one gather per edge slot and the
+  scatter-min by row) and the shard winners merge
   with one ``jax.lax.pmin`` — a single all-reduce per BFS level, the
   minimal coordination any level-synchronous distributed BFS needs;
 * ``ALTERNATE``/``FIXMATCHING`` act on replicated O(n) state and therefore
@@ -82,12 +81,6 @@ class ShardedMatcher(Matcher):
                  config: MatcherConfig = MatcherConfig(),
                  warm_start: str = "none"):
         super().__init__(config, warm_start)
-        if self.config.adaptive_frontier:
-            raise ValueError(
-                "adaptive_frontier is single-device only; ShardedMatcher "
-                "keeps the dense per-shard sweep + one pmin per level "
-                "(use MatcherConfig(dirop=True) for a direction heuristic "
-                "that composes with sharding)")
         assert axis in mesh.axis_names, (axis, mesh.axis_names)
         self.mesh = auto_mesh(mesh)
         self.axis = axis

@@ -4,31 +4,25 @@ Mapping from the paper's CUDA kernels to TPU-friendly vector ops
 ----------------------------------------------------------------
 The paper launches one CUDA thread per column (MT) or a constant thread grid
 (CT), each walking its CSR adjacency with benign write races.  Here a BFS
-level is a single *edge-parallel* vector operation over all ``nnz`` edges:
+level is a single *edge-parallel* vector operation over all ``nnz`` edges,
+so the paper's MT/CT thread-grid choice has no counterpart:
 
 * the per-thread race "first writer wins" becomes a deterministic
   ``min``-merge (lowest proposing column wins) — same semantics class the
-  paper relies on, but reproducible.  Three interchangeable sweeps produce
-  the identical per-row winner vector: the jnp path (the XLA sweep the chip
-  runs: the column side of the proposal predicate gathered once per edge,
-  the scatter-min by row, then the row side applied to the O(nr) winner
-  vector), the legacy Pallas path (per-edge proposal kernel + XLA scatter)
-  and the fused Pallas path (winner accumulator merged inside the kernel,
-  no (nnz,) intermediate — the default when ``use_pallas``);
-* beyond-paper, ``adaptive_frontier`` tracks the frontier size each level
-  and swaps the dense O(nnz) sweep for a compact column-gather sweep
-  (O(cap·dmax)) whenever the frontier is small enough, with a runtime
-  fallback that keeps the result bit-identical;
+  paper relies on, but reproducible.  The push sweep is the XLA sweep
+  :func:`_winner_xla`: the column side of the proposal predicate gathered
+  once per edge, the scatter-min by row, then the row side applied to the
+  O(nr) winner vector;
 * beyond-paper, ``dirop`` is the direction-optimizing engine: each level a
   Beamer-style heuristic compares the frontier's outgoing-edge count
   against the unreached rows' incoming-edge count (both O(n) degree sums
   off ``cxadj``/``rxadj``) and ``lax.cond``-dispatches either the push
   sweep or a *pull* sweep over the CSC mirror — a compact row-gather
-  (O(cap·dmax)) on the jnp path, the tile-skipping
-  ``frontier_expand_pull`` kernel on the Pallas path.  The proposal
-  predicate factors into a column side and a row side, so pull and push
-  enumerate the same proposals and the min-merge winner is bit-identical
-  whichever direction ran — the heuristic is a pure performance decision;
+  (O(cap·dmax)) on one device, the XLA sweep over the row-sorted edge
+  shard under ``shard_map``.  The proposal predicate factors into a
+  column side and a row side, so pull and push enumerate the same
+  proposals and the min-merge winner is bit-identical whichever direction
+  ran — the heuristic is a pure performance decision;
 * ``ALTERNATE`` (Alg. 3) walks all augmenting paths in lock-step inside a
   ``lax.while_loop``; the paper's line-8 predecessor check is a vector mask;
 * ``FIXMATCHING`` is the paper's repair pass, applied in both directions so
@@ -57,10 +51,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# the one definition of the TPU lane width (floor for any edge tile) lives
-# next to the kernels that tile over it
-from repro.kernels.frontier_expand import LANE
-
 from .config import MatcherConfig
 
 L0 = jnp.int32(2)            # paper's suggested start level (keeps bfs positive)
@@ -84,7 +74,7 @@ def level0_state(cmatch):
     """BFS state at the paper's start level for a given matching: ``bfs``
     (unmatched columns are L0 roots, matched UNVISITED, sentinel NEG) and
     ``root`` (own index if root).  The exact init ``phase_bfs`` performs —
-    shared with the kernel benches/tests so their probe states cannot drift
+    shared with the sweep benches and tests so their probe states cannot drift
     from what the solver actually sweeps.
     """
     nc = cmatch.shape[0] - 1
@@ -94,43 +84,9 @@ def level0_state(cmatch):
     return bfs, root
 
 
-def default_block_edges(nnz_pad: int, schedule: str) -> int:
-    """Edge-tile size for the Pallas frontier kernel.
-
-    CT: big fixed tile (constant "thread" count, coarse grain);
-    MT: one-edge-per-lane fine grain -> smaller tiles.
-
-    Never degenerate: the kernel wrappers pad the edge arrays up to a tile
-    multiple, so the tile no longer has to divide ``nnz_pad`` (the old
-    ``gcd`` collapsed to 1-lane tiles on prime edge counts).  The result is
-    always a multiple of the 128-lane width, floor 128.
-    """
-    desired = 4096 if schedule == "ct" else 512
-    return min(desired, -(-nnz_pad // LANE) * LANE)
-
-
 # ---------------------------------------------------------------------------
 # BFS level expansion — the paper's Algorithms 2 (GPUBFS) and 4 (GPUBFS-WR)
 # ---------------------------------------------------------------------------
-def _winner_full(ecol, cadj, bfs, root, rmatch, level, nr, *, use_pallas: bool,
-                 pallas_fused: bool, block_edges: int,
-                 interpret: Optional[bool]):
-    """Dense O(nnz) sweep -> per-row winner vector (nr+1,): the XLA sweep
-    (one column-side gather per edge, the scatter-min, the row side on the
-    winner vector) unless ``use_pallas``."""
-    if not use_pallas:
-        return _winner_xla(ecol, cadj, bfs, root, rmatch, level, nr)
-    if pallas_fused:
-        from repro.kernels.frontier_expand.ops import frontier_expand_fused
-        return frontier_expand_fused(ecol, cadj, bfs, root, rmatch, level,
-                                     block_edges=block_edges,
-                                     interpret=interpret)
-    from repro.kernels.frontier_expand.ops import frontier_expand
-    prop = frontier_expand(ecol, cadj, bfs, root, rmatch, level,
-                           block_edges=block_edges, interpret=interpret)
-    return scatter_min(nr, jnp.where(prop < IINF, cadj, nr), prop)
-
-
 def _winner_xla(ecol, cadj, bfs, root, rmatch, level, nr):
     """The XLA sweep: the proposal predicate factored into its two sides.
 
@@ -139,7 +95,7 @@ def _winner_xla(ecol, cadj, bfs, root, rmatch, level, nr):
     can wait for the winner vector.  Per edge this is ONE gather of the
     column side's value (the column, or IINF), then the scatter-min by row;
     both sides are O(n) work.  Bit-identical to scatter-merging the per-edge
-    ``_proposals`` formula the Pallas kernels tile.  ``ecol``/``cadj`` may
+    proposal formula (:mod:`repro.matching.sweep_ref`).  ``ecol``/``cadj`` may
     be any edge order (the CSC mirror's ``radj``/``erow`` too); padding
     edges (``ecol = nc``, ``cadj = nr``) read the sentinel column, which
     never proposes, into the discard slot.
@@ -205,54 +161,6 @@ def _winner_pull_compact(rxadj, radj, bfs, root, rmatch, level, nr,
     return scatter_min(nr, jnp.minimum(rows, nr), win_rows)
 
 
-def _winner_pull_stream(radj, erow, bfs, root, rmatch, level, nr, *,
-                        use_pallas: bool, block_edges: int,
-                        interpret: Optional[bool]):
-    """Streaming pull sweep over the (possibly sharded) CSC edge list.
-
-    On the Pallas path this is ``frontier_expand_pull`` — row-sorted tiles
-    whose in-VMEM merge skips when the tile proposes nothing.  The jnp form
-    is the dense sweep on the permuted arrays (no asymptotic win — it
-    exists so the sharded jnp path can follow the same direction decision
-    with bit-identical winners).
-    """
-    if use_pallas:
-        from repro.kernels.frontier_expand.ops import frontier_expand_pull
-        return frontier_expand_pull(radj, erow, bfs, root, rmatch, level,
-                                    block_edges=block_edges,
-                                    interpret=interpret)
-    return _winner_xla(radj, erow, bfs, root, rmatch, level, nr)
-
-
-def _winner_compact(cxadj, cadj, bfs, rmatch, nr, isf, *,
-                    cap: int, dmax: int):
-    """Compact column-gather sweep: O(cap·dmax) instead of O(nnz).
-
-    ``isf`` is the (nc,) frontier mask (WR refinement already applied) the
-    caller computed for the eligibility guard — passed in rather than
-    recomputed because XLA cannot CSE across the ``lax.cond`` boundary.
-    Gathers up to ``cap`` frontier columns and up to ``dmax`` edges each via
-    ``cxadj`` offsets.  Only called when the eligibility guard holds
-    (frontier fits the capacity), in which case every proposal of the dense
-    sweep is present and the min-merge winner is bit-identical.
-    """
-    nc = bfs.shape[0] - 1
-    nnz_pad = cadj.shape[0]
-    cols = jnp.nonzero(isf, size=cap, fill_value=nc)[0]         # (cap,)
-    starts = cxadj[jnp.minimum(cols, nc)]
-    ends = cxadj[jnp.minimum(cols + 1, nc)]                     # fill -> deg 0
-    offs = jnp.arange(dmax, dtype=jnp.int32)
-    eidx = starts[:, None] + offs[None, :]                      # (cap, dmax)
-    valid = offs[None, :] < (ends - starts)[:, None]
-    rows = jnp.where(valid, cadj[jnp.clip(eidx, 0, nnz_pad - 1)], nr)
-    cm = rmatch[rows]
-    col_unvis = bfs[jnp.clip(cm, 0, nc)] == UNVISITED
-    target = valid & ((cm >= 0) & col_unvis | (cm == -1))
-    prop = jnp.where(target, cols[:, None], IINF)
-    rows_ix = jnp.where(target, rows, nr)
-    return scatter_min(nr, rows_ix.ravel(), prop.ravel())
-
-
 def _apply_winner(winner, bfs, root, pred, rmatch, level, *, wr: bool,
                   wr_exact: bool):
     """Fold a per-row winner vector into the BFS state (the paper's Alg. 2
@@ -290,56 +198,22 @@ def _apply_winner(winner, bfs, root, pred, rmatch, level, *, wr: bool,
 
 
 def _expand_level(ecol, cadj, bfs, root, pred, rmatch, level, *, wr: bool,
-                  wr_exact: bool, use_pallas: bool, block_edges: int,
-                  axis: Optional[str] = None, pallas_fused: bool = True,
-                  interpret: Optional[bool] = None, cxadj=None,
-                  adaptive: bool = False, compact_cap: int = 0,
-                  compact_dmax: int = 0):
+                  wr_exact: bool, axis: Optional[str] = None):
     """One level-synchronous frontier expansion. Returns updated state.
 
     Edge-parallel: every edge (c, r) is one lane.  The per-row conflict
     (several frontier columns reaching the same row) is resolved with a
-    deterministic min-merge, standing in for the paper's benign race — fused
-    into the Pallas kernel on the default Pallas path, a separate scatter on
-    the jnp and legacy paths.
+    deterministic min-merge, standing in for the paper's benign race.
 
     With ``axis`` set (inside ``shard_map``), ``ecol``/``cadj`` are this
     device's edge shard and the per-row winners of all shards merge with one
     ``lax.pmin`` over the mesh axis — the single collective any
     level-synchronous distributed BFS needs.  Everything after the merge
     operates on replicated O(n) state and is bit-identical on every device.
-
-    ``adaptive`` (requires ``cxadj``, single-device) sizes the frontier each
-    level and dispatches the compact column-gather sweep when it fits; the
-    compact geometry must be resolved through ``MatcherConfig`` (0 = not
-    resolved is an error here — there is no untracked default).
     """
     nr = pred.shape[0] - 1
     rt = root if wr else None
-
-    def full(_):
-        return _winner_full(ecol, cadj, bfs, rt, rmatch, level, nr,
-                            use_pallas=use_pallas, pallas_fused=pallas_fused,
-                            block_edges=block_edges, interpret=interpret)
-
-    if adaptive:
-        assert cxadj is not None, "adaptive_frontier needs the cxadj offsets"
-        assert axis is None, "adaptive_frontier is single-device only"
-        assert compact_cap > 0 and compact_dmax > 0, \
-            "resolve the compact geometry via MatcherConfig.resolve_cap/" \
-            "resolve_dmax (0 means unresolved, not a default)"
-        isf = _active_cols(bfs, rt, level)[:-1]
-        deg = cxadj[1:] - cxadj[:-1]
-        eligible = ((jnp.sum(isf.astype(jnp.int32)) <= compact_cap)
-                    & (jnp.max(jnp.where(isf, deg, 0)) <= compact_dmax))
-        winner = jax.lax.cond(
-            eligible,
-            lambda _: _winner_compact(cxadj, cadj, bfs, rmatch, nr, isf,
-                                      cap=compact_cap, dmax=compact_dmax),
-            full, None)
-    else:
-        winner = full(None)
-
+    winner = _winner_xla(ecol, cadj, bfs, rt, rmatch, level, nr)
     if axis is not None:                                  # merge edge shards
         with jax.named_scope("merge_shards"):
             winner = jax.lax.pmin(winner, axis)
@@ -349,10 +223,9 @@ def _expand_level(ecol, cadj, bfs, root, pred, rmatch, level, *, wr: bool,
 
 def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
                         pred, rmatch, level, dir_prev, *, wr: bool,
-                        wr_exact: bool, use_pallas: bool, block_edges: int,
-                        axis: Optional[str], pallas_fused: bool,
-                        interpret: Optional[bool], dirop_alpha: float,
-                        dirop_beta: float, pull_cap: int, pull_dmax: int):
+                        wr_exact: bool, axis: Optional[str],
+                        dirop_alpha: float, dirop_beta: float, pull_cap: int,
+                        pull_dmax: int):
     """Direction-optimizing frontier expansion (Beamer-style, in-jit).
 
     Estimates both directions' work from O(n) degree sums — the frontier
@@ -364,11 +237,12 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
     * once pulling, keep pulling while ``fe * dirop_beta > pe`` (the
       hysteresis band, ``beta > alpha`` — ``dir_prev`` carries the previous
       level's direction through the BFS loop);
-    * the jnp pull is the compact row-gather and additionally requires the
-      unreached rows to fit its (cap, dmax) geometry; the Pallas pull and
-      the sharded path stream the CSC mirror, no geometry constraint.
+    * on one device the pull is the compact row-gather and additionally
+      requires the unreached rows to fit its (cap, dmax) geometry; the
+      sharded path streams its CSC shard through the XLA sweep, no
+      geometry constraint.
 
-    Either branch produces the dense sweep's exact winner vector, so the
+    Either branch produces the push sweep's exact winner vector, so the
     decision is invisible in the matching; with ``axis`` set the usual one
     ``lax.pmin`` merges the per-shard winners, whichever direction each
     level ran (the estimates are computed from replicated state, so every
@@ -378,10 +252,8 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
     nr = pred.shape[0] - 1
     rt = root if wr else None
 
-    def full(_):
-        return _winner_full(ecol, cadj, bfs, rt, rmatch, level, nr,
-                            use_pallas=use_pallas, pallas_fused=pallas_fused,
-                            block_edges=block_edges, interpret=interpret)
+    def push(_):
+        return _winner_xla(ecol, cadj, bfs, rt, rmatch, level, nr)
 
     isf = _active_cols(bfs, rt, level)[:-1]
     cdeg = cxadj[1:] - cxadj[:-1]
@@ -391,7 +263,7 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
     pe = jnp.sum(jnp.where(unreached, rdeg, 0)).astype(jnp.float32)
 
     use_pull = (fe * dirop_alpha > pe) | (dir_prev & (fe * dirop_beta > pe))
-    if axis is None and not use_pallas:
+    if axis is None:
         # compact pull: every unreached row must be gathered in full
         fits = ((jnp.sum(unreached.astype(jnp.int32)) <= pull_cap)
                 & (jnp.max(jnp.where(unreached, rdeg, 0)) <= pull_dmax))
@@ -400,11 +272,10 @@ def _expand_level_dirop(ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
             rxadj, radj, bfs, rt, rmatch, level, nr, unreached,
             cap=pull_cap, dmax=pull_dmax)
     else:
-        pull = lambda _: _winner_pull_stream(   # noqa: E731
-            radj, erow, bfs, rt, rmatch, level, nr, use_pallas=use_pallas,
-            block_edges=block_edges, interpret=interpret)
+        pull = lambda _: _winner_xla(           # noqa: E731
+            radj, erow, bfs, rt, rmatch, level, nr)
 
-    winner = jax.lax.cond(use_pull, pull, full, None)
+    winner = jax.lax.cond(use_pull, pull, push, None)
     if axis is not None:                                  # merge edge shards
         with jax.named_scope("merge_shards"):
             winner = jax.lax.pmin(winner, axis)
@@ -520,40 +391,28 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
     level's ``pmin``).  They are metadata only; the last component of
     every ``op_name`` stays the JAX primitive.
 
-    Shape-polymorphic: ``nc``/``nr``/``block_edges`` are derived from the
-    argument shapes at trace time, so one returned function serves every size
-    bucket and closes under ``jit`` and ``vmap``.
+    Shape-polymorphic: ``nc``/``nr`` are derived from the argument shapes
+    at trace time, so one returned function serves every size bucket and
+    closes under ``jit`` and ``vmap``.
 
     ``axis`` names a mesh axis for the distributed variant: the returned
     function then expects to run *inside* ``shard_map`` with ``ecol``/``cadj``
     edge-sharded over that axis and the O(n) state replicated.  The only
-    communication is one ``pmin`` per BFS level in :func:`_expand_level` —
-    on the fused Pallas path each shard's kernel already emits its local
-    per-row winner vector, so the pmin is the whole merge.  ALTERNATE and
+    communication is one ``pmin`` per BFS level in :func:`_expand_level`,
+    which merges the shards' per-row winner vectors.  ALTERNATE and
     FIXMATCHING run redundantly-but-identically on the replicated state
     (their cost is O(n) per phase vs O(nnz/D) for expansion, so sharding
     them would buy nothing).
 
-    ``cfg.adaptive_frontier`` additionally needs the ``cxadj`` offsets
-    (pass ``match_fn(..., cxadj=graph.cxadj)``) and is single-device only.
     ``cfg.dirop`` needs ``cxadj`` plus the CSC mirror arrays
     (``rxadj``/``radj``/``erow`` of ``DeviceCSR.with_csc``); it composes
     with ``axis`` — each shard pulls over its own CSC slice and the same
     single ``pmin`` merges the winners.
     """
     wr = cfg.kernel == "gpubfs_wr"
-    if cfg.adaptive_frontier and axis is not None:
-        raise ValueError(
-            "adaptive_frontier composes with the dense per-shard sweep only; "
-            "disable it for ShardedMatcher (axis=%r); dirop is the "
-            "direction heuristic that does compose with sharding" % (axis,))
 
     def match_fn(ecol, cadj, cmatch, rmatch, cxadj=None, rxadj=None,
                  radj=None, erow=None):
-        if cfg.adaptive_frontier and cxadj is None:
-            raise ValueError(
-                "adaptive_frontier needs the cxadj column offsets; call the "
-                "solver with cxadj= (Matcher.solve passes graph.cxadj)")
         if cfg.dirop and (cxadj is None or rxadj is None or radj is None
                           or erow is None):
             raise ValueError(
@@ -562,12 +421,8 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
                 "it through when present")
         nc = cmatch.shape[0] - 1
         nr = rmatch.shape[0] - 1
-        block_edges = cfg.pallas_block_edges or default_block_edges(
-            int(ecol.shape[0]), cfg.schedule)
-        # compact/pull geometry: the ONE auto rule lives on MatcherConfig
-        # (pure in (config, bucket), so the 0 marker in cache keys is safe)
-        compact_cap = cfg.resolve_cap(cfg.compact_cap, nc)
-        compact_dmax = cfg.resolve_dmax(cfg.compact_dmax)
+        # pull geometry: the ONE auto rule lives on MatcherConfig (pure in
+        # (config, bucket), so the 0 marker in cache keys is safe)
         pull_cap = cfg.resolve_cap(cfg.pull_cap, nr)
         pull_dmax = cfg.resolve_dmax(cfg.pull_dmax)
 
@@ -595,23 +450,14 @@ def make_solver(cfg: MatcherConfig, axis: Optional[str] = None):
                         _expand_level_dirop(
                             ecol, cadj, cxadj, rxadj, radj, erow, bfs, root,
                             pred, rmatch, level, dirp, wr=wr,
-                            wr_exact=cfg.wr_exact, use_pallas=cfg.use_pallas,
-                            block_edges=block_edges, axis=axis,
-                            pallas_fused=cfg.pallas_fused,
-                            interpret=cfg.pallas_interpret,
+                            wr_exact=cfg.wr_exact, axis=axis,
                             dirop_alpha=cfg.dirop_alpha,
                             dirop_beta=cfg.dirop_beta,
                             pull_cap=pull_cap, pull_dmax=pull_dmax)
                 else:
                     bfs, root, pred, rmatch, ins, aug_l = _expand_level(
                         ecol, cadj, bfs, root, pred, rmatch, level, wr=wr,
-                        wr_exact=cfg.wr_exact, use_pallas=cfg.use_pallas,
-                        block_edges=block_edges, axis=axis,
-                        pallas_fused=cfg.pallas_fused,
-                        interpret=cfg.pallas_interpret, cxadj=cxadj,
-                        adaptive=cfg.adaptive_frontier,
-                        compact_cap=compact_cap,
-                        compact_dmax=compact_dmax)
+                        wr_exact=cfg.wr_exact, axis=axis)
                 aug_lvl = jnp.where(aug_l & (aug_lvl == IINF), level, aug_lvl)
                 return (bfs, root, pred, rmatch, level + 1, ins, aug | aug_l,
                         aug_lvl, dirp)

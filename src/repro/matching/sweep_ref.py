@@ -1,4 +1,6 @@
-"""Pure-jnp oracles for the frontier-expansion kernels.
+"""Pure-jnp oracles for one BFS level's sweep: the reference the solver's
+sweeps (:func:`repro.matching.solve._winner_xla` and the pull sweeps) are
+held bit-identical to in the tests.
 
 Semantics (one BFS level of the paper's Alg. 2 / Alg. 4, proposal half):
 for every edge e = (c, r):
@@ -7,11 +9,12 @@ for every edge e = (c, r):
                          or rmatch[r] == -1 )
   out[e]  = c if propose else IINF
 
-:func:`frontier_expand_ref` is that proposal sweep alone (the legacy kernel
-contract); :func:`frontier_expand_fused_ref` composes it with the
-deterministic per-row min-merge ("first writer wins" = lowest proposing
-column), which is the fused kernel's contract: a ``(nr+1,)`` winner vector
-with IINF in every unreached row and in the trailing sentinel slot.
+:func:`frontier_expand_ref` is that per-edge proposal sweep alone;
+:func:`frontier_expand_fused_ref` composes it with the deterministic per-row
+min-merge ("first writer wins" = lowest proposing column): a ``(nr+1,)``
+winner vector with IINF in every unreached row and in the trailing sentinel
+slot.  Written straight from the formula, independent of the solver's
+factored sweep.
 """
 from __future__ import annotations
 
@@ -33,7 +36,7 @@ def frontier_expand_ref(ecol, cadj, bfs, root, rmatch, level):
 
 
 def frontier_expand_fused_ref(ecol, cadj, bfs, root, rmatch, level):
-    """Proposals + per-row min-merge: the fused kernel's oracle."""
+    """Proposals + per-row min-merge: the per-row winner oracle."""
     nr = rmatch.shape[0] - 1
     prop = frontier_expand_ref(ecol, cadj, bfs, root, rmatch, level)
     rows = jnp.where(prop < IINF, cadj, jnp.int32(nr))
@@ -42,7 +45,7 @@ def frontier_expand_fused_ref(ecol, cadj, bfs, root, rmatch, level):
 
 
 def frontier_expand_pull_ref(radj, erow, bfs, root, rmatch, level):
-    """Pull-kernel oracle: the same min-merge over the row-sorted (CSC)
-    edge view — the proposal predicate is per-edge and min is the merge,
-    so this is definitionally the fused oracle on permuted arrays."""
+    """Pull oracle: the same min-merge over the row-sorted (CSC) edge view —
+    the proposal predicate is per-edge and min is the merge, so this is
+    definitionally the winner oracle on permuted arrays."""
     return frontier_expand_fused_ref(radj, erow, bfs, root, rmatch, level)

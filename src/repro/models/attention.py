@@ -212,9 +212,9 @@ def attention(params, x, pos, cfg: ModelConfig, *, mask_kind: str,
         kpos = kv_pos
         mask_kind = "bidir"
     q = constrain(q, P(AX_DATA, None, AX_MODEL, None))
-    use_pallas = (cfg.attn_impl == "pallas" and kv_x is None
-                  and mask_kind in ("causal", "bidir"))
-    if use_pallas:
+    flash = (cfg.attn_impl == "pallas" and kv_x is None
+             and mask_kind in ("causal", "bidir"))
+    if flash:
         from repro.kernels.flash_attention.ops import flash_attention
         out = flash_attention(q, k, v, causal=(mask_kind == "causal"))
     elif cfg.opt_attn_layout and kv_x is None:

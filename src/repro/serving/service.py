@@ -267,13 +267,6 @@ class MatchingService:
                 warm_start: Optional[str] = None) -> Matcher:
         cfg = config if config is not None else self.config
         ws = warm_start if warm_start is not None else self.warm_start
-        if cfg.adaptive_frontier:
-            # run_many (the only dispatch path here) refuses this config;
-            # surface that in the caller's thread, not on the flush thread
-            # after the batching delay (dirop is the batch-safe variant)
-            raise ValueError(
-                "adaptive_frontier cannot be served (Matcher.run_many "
-                "refuses it under vmap); use MatcherConfig(dirop=True)")
         key = (cfg, ws)
         m = self._matchers.get(key)
         if m is None:

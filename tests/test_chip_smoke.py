@@ -8,6 +8,7 @@ stays off: tests write no cache.
 import importlib.util
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -45,7 +46,11 @@ def test_chip_smoke_one_chip_phases_on_cpu(smoke, monkeypatch, capsys):
         "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     for phase in ("single", "batch", "service"):
         assert f"[{phase}] ok" in "\n".join(lines), phase
-    assert sum("pallas_interpret=True" in ln for ln in lines) == 5
+    # three single-graph runs, the batch and the service each name the
+    # variant and warm start they ran
+    assert sum(bool(re.search(r" apfb-gpubfs_wr\+(karp_sipser|cheap) |"
+                              r" apsb-gpubfs_wr-exact\+karp_sipser ", ln))
+               for ln in lines) == 5
 
 
 def test_chip_smoke_refuses_a_host_without_the_chip(smoke, capsys):

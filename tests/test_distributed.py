@@ -68,37 +68,10 @@ assert info3["misses"] == info2["misses"] + 1, (info2, info3)
 print("DIST_OK")
 """
 
-# The fused Pallas frontier kernel as the per-shard sweep: each shard's
-# winner merge happens inside its kernel, one pmin merges the shards, and
-# the result must be BIT-identical to the single-device jnp path (the
-# deterministic min-merge makes every sweep path interchangeable).
-PALLAS = PRELUDE + """
-import dataclasses
-g = cases["rand"]
-opt = maximum_cardinality(g)
-graph = DeviceCSR.from_host(g)
-sharded_g = graph.shard(mesh, "data")
-for schedule in ("ct", "mt"):
-    cfg = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule=schedule,
-                        use_pallas=True)
-    single = Matcher(dataclasses.replace(cfg, use_pallas=False),
-                     warm_start="cheap").run(graph)
-    for fused in (True, False):
-        fcfg = dataclasses.replace(cfg, pallas_fused=fused)
-        st = ShardedMatcher(mesh, config=fcfg, warm_start="cheap").run(sharded_g)
-        cm, rm = st.to_host()
-        assert validate_matching(g, cm, rm) == opt, (schedule, fused)
-        np.testing.assert_array_equal(np.asarray(st.cmatch),
-                                      np.asarray(single.cmatch))
-        np.testing.assert_array_equal(np.asarray(st.rmatch),
-                                      np.asarray(single.rmatch))
-print("DIST_OK")
-"""
-
 # Direction-optimizing engine on the sharded path: each shard pulls over
-# its own CSC slice (jnp stream or the Pallas pull kernel), the one pmin
-# still merges, and the result must be BIT-identical to the single-device
-# jnp path across algos.  Also: the mirror must be attached before shard().
+# its own CSC slice through the XLA sweep, the one pmin still merges, and
+# the result must be BIT-identical to the single-device push path across
+# algos.  Also: the mirror must be attached before shard().
 DIROP = PRELUDE + """
 import dataclasses
 g = cases["rand"]
@@ -106,19 +79,16 @@ opt = maximum_cardinality(g)
 graph = DeviceCSR.from_host(g)
 sharded_g = graph.with_csc().shard(mesh, "data")
 for algo in ("apfb", "apsb"):
-    for use_pallas in (False, True):
-        cfg = MatcherConfig(algo=algo, kernel="gpubfs_wr", dirop=True,
-                            use_pallas=use_pallas)
-        single = Matcher(dataclasses.replace(cfg, dirop=False,
-                                             use_pallas=False),
-                         warm_start="cheap").run(graph)
-        st = ShardedMatcher(mesh, config=cfg, warm_start="cheap").run(sharded_g)
-        cm, rm = st.to_host()
-        assert validate_matching(g, cm, rm) == opt, (algo, use_pallas)
-        np.testing.assert_array_equal(np.asarray(st.cmatch),
-                                      np.asarray(single.cmatch))
-        np.testing.assert_array_equal(np.asarray(st.rmatch),
-                                      np.asarray(single.rmatch))
+    cfg = MatcherConfig(algo=algo, kernel="gpubfs_wr", dirop=True)
+    single = Matcher(dataclasses.replace(cfg, dirop=False),
+                     warm_start="cheap").run(graph)
+    st = ShardedMatcher(mesh, config=cfg, warm_start="cheap").run(sharded_g)
+    cm, rm = st.to_host()
+    assert validate_matching(g, cm, rm) == opt, algo
+    np.testing.assert_array_equal(np.asarray(st.cmatch),
+                                  np.asarray(single.cmatch))
+    np.testing.assert_array_equal(np.asarray(st.rmatch),
+                                  np.asarray(single.rmatch))
 try:
     ShardedMatcher(mesh, config=MatcherConfig(dirop=True)).run(
         DeviceCSR.from_host(g).shard(mesh, "data"))
@@ -207,9 +177,8 @@ for g in (kron_graph(10, 8, seed=6), cases["free"]):
 print("DIST_OK")
 """
 
-SCENARIOS = {"equality": EQUALITY, "cache": CACHE, "pallas": PALLAS,
-             "dirop": DIROP, "compat": COMPAT, "merge": MERGE,
-             "warm_rounds": WARM_ROUNDS}
+SCENARIOS = {"equality": EQUALITY, "cache": CACHE, "dirop": DIROP,
+             "compat": COMPAT, "merge": MERGE, "warm_rounds": WARM_ROUNDS}
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))

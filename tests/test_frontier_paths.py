@@ -1,26 +1,24 @@
 """Every frontier-sweep execution path against the one deterministic
-min-merge contract (ISSUE 4: the fused kernel must be bit-identical to the
-scatter_min-merged proposals on all variants; ISSUE 5: so must the pull
-sweeps of the direction-optimizing engine), plus the edge-tile geometry
-fixes and the ALTERNATE micro-optimizations.
+min-merge contract: the push sweep and both pull sweeps of the
+direction-optimizing engine must give the per-row winners of the pure-jnp
+oracle (:mod:`repro.matching.sweep_ref`) bit for bit, plus the ALTERNATE
+micro-optimizations.
 
 Split by concern:
-* kernel-level: fused winners == scatter_min(legacy proposals) == fused ref
-  == pull winners over the CSC-permuted edges;
-* the XLA sweep: its column-side gather plus row-side mask == scatter_min of
-  the per-edge proposal formula, on random mid-phase states, WR and plain,
-  alone and under vmap;
+* the XLA sweep: its column-side gather plus row-side mask == the oracle,
+  on random mid-phase states (alone and under vmap), on level-L0 states of
+  graphs of several shapes and on a power-law graph one level deeper, WR
+  and plain;
+* the pull sweeps: the compact row-gather and the stream over the CSC
+  mirror == the push winners == the pull oracle;
 * CSC mirror: `DeviceCSR.with_csc` agrees with the host transpose and rides
   every shape operation (pad_to / pad_vertices / stack);
-* solver-level: jnp / Pallas-interpret / adaptive / dirop sweeps give
-  bit-identical matchings across the paper's variant matrix and both WR
-  encodings (compiled Pallas is refused on the chip, see
-  tests/test_tpu_compile.py);
+* solver-level: the push and dirop paths give bit-identical matchings
+  across the paper's variant matrix, both WR encodings and three graph
+  families;
 * dirop: forced-pull and forced-push runs agree; the compact pull falls
-  back cleanly on skewed degrees; config plumbing (mirror errors, the
-  adaptive/dirop exclusion, hysteresis bounds) fails loudly;
-* geometry: `default_block_edges` no longer degenerates on prime edge
-  counts, bad tiles raise a typed ValueError at trace time;
+  back cleanly on skewed degrees; config plumbing (mirror errors,
+  hysteresis bounds) fails loudly;
 * ALTERNATE: the gather-hoisted, scatter-skipping loop is a step-count-
   preserving rewrite of the straightforward body.
 """
@@ -34,18 +32,15 @@ import pytest
 from repro.core import (MatcherConfig, VARIANTS, cheap_matching_jax,
                         maximum_cardinality, maximum_matching,
                         validate_matching)
-from repro.graphs import random_bipartite, scaled_free
-from repro.kernels.frontier_expand import (frontier_expand,
-                                           frontier_expand_fused,
-                                           frontier_expand_fused_ref,
-                                           frontier_expand_pull,
-                                           frontier_expand_pull_ref,
-                                           resolve_interpret)
+from repro.graphs import grid_graph, random_bipartite, scaled_free
 from repro.matching import DeviceCSR, Matcher, SOLVE_PATHS
-from repro.kernels.frontier_expand.frontier_expand import _proposals
 from repro.matching.solve import (FOUND, IINF, NEG, UNVISITED, _alternate,
-                                  _winner_full, default_block_edges,
+                                  _expand_level, _unreached_rows,
+                                  _winner_pull_compact, _winner_xla,
                                   level0_state, scatter_min)
+from repro.matching.sweep_ref import (frontier_expand_fused_ref,
+                                      frontier_expand_pull_ref)
+
 
 def _bfs_state(g):
     """Level-L0 probe state via the solver's own ``level0_state`` init."""
@@ -54,53 +49,6 @@ def _bfs_state(g):
     rmj = jnp.concatenate([jnp.asarray(rm), jnp.array([-3], jnp.int32)])
     bfs, root = level0_state(cmj)
     return bfs, root, rmj
-
-
-# ---------------------------------------------------------------------------
-# kernel level
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("nc,nr,deg,pad,blk", [
-    (256, 256, 3.0, 1024, 256),
-    (500, 700, 4.0, 3000, 512),      # pad not a multiple of the tile
-    (300, 200, 5.0, 2048, 999),      # tile not a divisor of anything nice
-    (64, 64, 2.0, 128, 4096),        # tile bigger than the edge array
-])
-def test_fused_kernel_bit_identical_to_scatter_min(nc, nr, deg, pad, blk):
-    g = random_bipartite(nc, nr, deg, seed=nc + nr, pad_to=pad)
-    bfs, root, rmj = _bfs_state(g)
-    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
-    for rt in (root, None):
-        prop = frontier_expand(ecol, cadj, bfs, rt, rmj, 2, block_edges=blk)
-        merged = scatter_min(nr, jnp.where(prop < IINF, cadj, nr), prop)
-        fused = frontier_expand_fused(ecol, cadj, bfs, rt, rmj, 2,
-                                      block_edges=blk)
-        ref = frontier_expand_fused_ref(ecol, cadj, bfs, rt, rmj,
-                                        jnp.int32(2))
-        np.testing.assert_array_equal(np.asarray(fused), np.asarray(merged))
-        np.testing.assert_array_equal(np.asarray(fused), np.asarray(ref))
-
-
-@pytest.mark.parametrize("nc,nr,deg,pad,blk", [
-    (256, 256, 3.0, 1024, 256),
-    (500, 700, 4.0, 3000, 512),      # pad not a multiple of the tile
-    (300, 200, 5.0, 2048, 999),      # tile not a divisor of anything nice
-])
-def test_pull_kernel_bit_identical_to_push_winners(nc, nr, deg, pad, blk):
-    """The pull kernel streams the CSC-permuted edges; min is the merge, so
-    its winners must equal the fused/push winners bit for bit."""
-    g = random_bipartite(nc, nr, deg, seed=nc + 3 * nr, pad_to=pad)
-    bfs, root, rmj = _bfs_state(g)
-    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
-    d = DeviceCSR.from_host(g).with_csc()
-    for rt in (root, None):
-        push = frontier_expand_fused(ecol, cadj, bfs, rt, rmj, 2,
-                                     block_edges=blk)
-        pull = frontier_expand_pull(d.radj, d.erow, bfs, rt, rmj, 2,
-                                    block_edges=blk)
-        ref = frontier_expand_pull_ref(d.radj, d.erow, bfs, rt, rmj,
-                                       jnp.int32(2))
-        np.testing.assert_array_equal(np.asarray(pull), np.asarray(push))
-        np.testing.assert_array_equal(np.asarray(pull), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------
@@ -136,38 +84,104 @@ def _mid_phase_state(seed, nc=150, nr=170, nnz=1200, pad=200, level=3):
             i32(rmatch), jnp.int32(level))
 
 
-def _winner_per_edge(ecol, cadj, bfs, root, rmatch, level):
-    """The per-edge formula the Pallas kernels tile, merged by scatter_min."""
-    nr = rmatch.shape[0] - 1
-    t = _proposals(level, ecol, cadj, bfs, root, rmatch)
-    return scatter_min(nr, jnp.where(t, cadj, nr), jnp.where(t, ecol, IINF))
+# level-L0 states of random graphs: (nc, nr, average degree, edge slots),
+# square and rectangular, the slots padded past the edges (to a power of
+# two or not)
+GRAPH_SHAPES = {
+    "256x256": (256, 256, 3.0, 1024),
+    "500x700": (500, 700, 4.0, 3000),
+    "500x700-pow2": (500, 700, 4.0, 4096),
+    "300x200": (300, 200, 5.0, 2048),
+    "1000x1000": (1000, 1000, 6.0, 8192),
+    "64x64": (64, 64, 2.0, 128),
+    "777x333": (777, 333, 5.0, 4096),
+}
+
+
+def _graph_state(nc, nr, deg, pad):
+    g = random_bipartite(nc, nr, deg, seed=nc + nr, pad_to=pad)
+    bfs, root, rmj = _bfs_state(g)
+    return (jnp.asarray(g.ecol), jnp.asarray(g.cadj), bfs, root, rmj,
+            jnp.int32(2))
+
+
+def _deeper_state():
+    """A power-law graph advanced one level by the solver's own expansion,
+    so the frontier is level L0+1 and some rows are already reached."""
+    g = scaled_free(512, 512, 6.0, seed=3, pad_to=8192)
+    bfs, root, rmj = _bfs_state(g)
+    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
+    pred = jnp.full(g.nr + 1, jnp.int32(g.nc))
+    bfs, root, _, rmj, _, _ = _expand_level(ecol, cadj, bfs, root, pred, rmj,
+                                            jnp.int32(2), wr=True,
+                                            wr_exact=False)
+    return ecol, cadj, bfs, root, rmj, jnp.int32(3)
 
 
 def _winner_factored(ecol, cadj, bfs, root, rmatch, level):
-    return _winner_full(ecol, cadj, bfs, root, rmatch, level,
-                        rmatch.shape[0] - 1, use_pallas=False,
-                        pallas_fused=False, block_edges=128, interpret=None)
+    return _winner_xla(ecol, cadj, bfs, root, rmatch, level,
+                       rmatch.shape[0] - 1)
 
 
-@pytest.mark.parametrize("batch", [0, 4], ids=["one", "vmap"])
+@pytest.mark.parametrize("case", ["one", "vmap", *GRAPH_SHAPES,
+                                  "free-deeper"])
 @pytest.mark.parametrize("wr", [True, False], ids=["wr", "plain"])
-def test_xla_sweep_factored_winners_bit_identical(wr, batch):
-    seeds = range(3) if batch == 0 else [list(range(s, s + batch))
-                                         for s in (10, 20)]
-    for seed in seeds:
-        if batch == 0:
-            args = _mid_phase_state(seed)
-            ref, got = _winner_per_edge, _winner_factored
-        else:
-            args = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                *[_mid_phase_state(s) for s in seed])
-            ref, got = jax.vmap(_winner_per_edge), jax.vmap(_winner_factored)
+def test_xla_sweep_factored_winners_bit_identical(wr, case):
+    ref, got = frontier_expand_fused_ref, _winner_factored
+    if case == "one":
+        states = [_mid_phase_state(seed) for seed in range(3)]
+    elif case == "vmap":
+        states = [jax.tree.map(lambda *xs: jnp.stack(xs),
+                               *[_mid_phase_state(s) for s in range(b, b + 4)])
+                  for b in (10, 20)]
+        ref, got = jax.vmap(ref), jax.vmap(got)
+    elif case == "free-deeper":
+        states = [_deeper_state()]
+    else:
+        states = [_graph_state(*GRAPH_SHAPES[case])]
+    for i, args in enumerate(states):
         if not wr:
             args = args[:3] + (None,) + args[4:]
         want = np.asarray(ref(*args))
         assert (want < IINF).any() and (want == IINF).any()
         np.testing.assert_array_equal(np.asarray(got(*args)), want,
-                                      err_msg=f"seed {seed}")
+                                      err_msg=f"state {i}")
+
+
+# ---------------------------------------------------------------------------
+# the pull sweeps over the CSC mirror
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("nc,nr,deg,pad", [
+    (256, 256, 3.0, 1024),
+    (500, 700, 4.0, 3000),
+    (300, 200, 5.0, 2048),
+])
+@pytest.mark.parametrize("sweep", ["compact", "stream"])
+def test_pull_sweep_bit_identical_to_push_winners(sweep, nc, nr, deg, pad):
+    """Both pull sweeps enumerate the same proposals as the push sweep, over
+    the row-sorted edges; min is the merge, so their winners must equal the
+    push winners bit for bit.  The compact row-gather runs at the tightest
+    geometry that still gathers every unreached row in full."""
+    g = random_bipartite(nc, nr, deg, seed=nc + 3 * nr, pad_to=pad)
+    bfs, root, rmj = _bfs_state(g)
+    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
+    d = DeviceCSR.from_host(g).with_csc()
+    level = jnp.int32(2)
+    unreached = _unreached_rows(bfs, rmj)
+    rdeg = np.diff(np.asarray(d.rxadj))
+    cap = int(unreached.sum())
+    dmax = int(rdeg[np.asarray(unreached)].max())
+    for rt in (root, None):
+        push = _winner_xla(ecol, cadj, bfs, rt, rmj, level, nr)
+        if sweep == "compact":
+            pull = _winner_pull_compact(d.rxadj, d.radj, bfs, rt, rmj, level,
+                                        nr, unreached, cap=cap, dmax=dmax)
+        else:
+            pull = _winner_xla(d.radj, d.erow, bfs, rt, rmj, level, nr)
+        ref = frontier_expand_pull_ref(d.radj, d.erow, bfs, rt, rmj, level)
+        assert (np.asarray(push) < IINF).any()
+        np.testing.assert_array_equal(np.asarray(pull), np.asarray(push))
+        np.testing.assert_array_equal(np.asarray(pull), np.asarray(ref))
 
 
 # ---------------------------------------------------------------------------
@@ -216,47 +230,16 @@ def test_csc_mirror_matches_host_transpose_and_threads_through_ops():
 
 
 # ---------------------------------------------------------------------------
-# geometry
-# ---------------------------------------------------------------------------
-def test_default_block_edges_never_degenerate():
-    """The old gcd collapsed to 1-lane tiles on prime edge counts; the tile
-    is now clamped-desired with a 128-lane floor (padding absorbs the rest).
-    """
-    for nnz in (997, 1, 130, 2048, 4096, 65536, 99991):
-        for schedule in ("ct", "mt"):
-            blk = default_block_edges(nnz, schedule)
-            assert blk >= 128, (nnz, schedule, blk)
-            assert blk % 128 == 0, (nnz, schedule, blk)
-    assert default_block_edges(65536, "ct") == 4096    # CT coarse tiles
-    assert default_block_edges(65536, "mt") == 512     # MT fine tiles
-    assert default_block_edges(997, "ct") == 1024      # clamped to the pad
-    assert default_block_edges(64, "mt") == 128        # floor
-
-
-def test_bad_block_edges_raises_typed_error():
-    g = random_bipartite(64, 64, 2.0, seed=0, pad_to=256)
-    bfs, root, rmj = _bfs_state(g)
-    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
-    for entry in (frontier_expand, frontier_expand_fused,
-                  frontier_expand_pull):
-        with pytest.raises(ValueError, match=r"block_edges=0 for nnz=256"):
-            entry(ecol, cadj, bfs, root, rmj, 2, block_edges=0)
-        with pytest.raises(ValueError, match="block_edges"):
-            entry(ecol, cadj, bfs, root, rmj, 2, block_edges=-4)
-
-
-# ---------------------------------------------------------------------------
 # solver level: the full variant matrix, every sweep path
 # ---------------------------------------------------------------------------
 def _encoding_matrix():
-    """All eight variants, and for the WR kernel both endpoint encodings."""
+    """All four variants, and for the WR kernel both endpoint encodings."""
     out = {}
     for v in VARIANTS:
-        encs = (False, True) if v.kernel == "gpubfs_wr" else (False,)
-        for e in encs:
+        for e in ((False, True) if v.kernel == "gpubfs_wr" else (False,)):
             cfg = dataclasses.replace(v, wr_exact=e)
-            out[cfg.name + ("-exact" if e and not v.wr_exact else "")] = cfg
-    return sorted(out.values(), key=lambda c: (c.name, c.wr_exact))
+            out[cfg.name] = cfg
+    return [out[name] for name in sorted(out)]
 
 
 # the registered solve paths ARE the sweep-path list: anything added to
@@ -266,16 +249,24 @@ PATHS = {name: dict(p.overrides)
          for name, p in SOLVE_PATHS.items()
          if not p.sharded and p.runner is None and name != "jnp"}
 
+# uniform and long-diameter graphs, whose late BFS levels leave few
+# unreached rows, so dirop pulls there, and a power-law one, whose
+# high-degree rows the compact pull's degree bound turns away
+FAMILIES = {
+    "random_bipartite": lambda: random_bipartite(180, 170, 3.0, seed=17),
+    "scaled_free": lambda: scaled_free(200, 200, 4.0, seed=5),
+    "grid_graph": lambda: grid_graph(14),
+}
+
 
 def test_registry_covers_every_single_device_path():
-    assert set(PATHS) == {"legacy", "fused", "adaptive", "dirop",
-                          "dirop_pallas"}
+    assert set(PATHS) == {"dirop"}
 
 
-@pytest.mark.parametrize("cfg", _encoding_matrix(), ids=lambda c:
-                         f"{c.name}{'-exact' if c.wr_exact else ''}")
-def test_sweep_paths_bit_identical(cfg):
-    g = random_bipartite(180, 170, 3.0, seed=17)
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("cfg", _encoding_matrix(), ids=lambda c: c.name)
+def test_sweep_paths_bit_identical(cfg, family):
+    g = FAMILIES[family]()
     opt = maximum_cardinality(g)
     cm0, rm0 = cheap_matching_jax(g)
     ref_cm, ref_rm, st = maximum_matching(g, cfg, cm0, rm0)
@@ -285,20 +276,6 @@ def test_sweep_paths_bit_identical(cfg):
         cm, rm, pst = maximum_matching(g, pcfg, cm0, rm0)
         np.testing.assert_array_equal(ref_cm, cm, err_msg=pname)
         np.testing.assert_array_equal(ref_rm, rm, err_msg=pname)
-
-
-def test_adaptive_runtime_fallback_on_skewed_degrees():
-    """Power-law columns exceed dmax -> runtime falls back to the dense
-    sweep; the result must stay bit-identical and maximum."""
-    g = scaled_free(300, 300, 5.0, seed=3)
-    cfg = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
-    ref_cm, ref_rm, _ = maximum_matching(g, cfg)
-    acfg = dataclasses.replace(cfg, adaptive_frontier=True,
-                               compact_cap=64, compact_dmax=2)
-    cm, rm, _ = maximum_matching(g, acfg)
-    np.testing.assert_array_equal(ref_cm, cm)
-    np.testing.assert_array_equal(ref_rm, rm)
-    assert validate_matching(g, cm, rm) == maximum_cardinality(g)
 
 
 # ---------------------------------------------------------------------------
@@ -342,29 +319,12 @@ def test_dirop_requires_the_csc_mirror():
 
 
 def test_dirop_config_validation():
-    with pytest.raises(ValueError, match="generalizes"):
-        MatcherConfig(dirop=True, adaptive_frontier=True)
     with pytest.raises(AssertionError, match="hysteresis"):
         MatcherConfig(dirop_alpha=8.0, dirop_beta=4.0)  # beta < alpha
     # the dirop knobs are dataclass fields -> part of every cache key
     a = MatcherConfig(dirop=True)
     b = MatcherConfig(dirop=True, dirop_alpha=2.0, dirop_beta=2.0)
     assert a != b and hash(a) != hash(b)
-
-
-# ---------------------------------------------------------------------------
-# config / cache plumbing
-# ---------------------------------------------------------------------------
-def test_interpret_resolution_in_cache_key():
-    from repro.matching import Matcher
-    auto = Matcher(MatcherConfig(use_pallas=True))
-    assert auto.config.pallas_interpret == (jax.default_backend() == "cpu")
-    assert resolve_interpret(None) == auto.config.pallas_interpret
-    pinned = Matcher(MatcherConfig(use_pallas=True, pallas_interpret=True))
-    assert pinned.config.pallas_interpret is True
-    # the resolved bool (not the None marker) is what lands in cache keys
-    assert auto.config == MatcherConfig(
-        use_pallas=True, pallas_interpret=auto.config.pallas_interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -4,55 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import cheap_matching_jax
-from repro.graphs import random_bipartite, scaled_free
 from repro.kernels.flash_attention import flash_attention, flash_attention_ref
-from repro.kernels.frontier_expand import frontier_expand, frontier_expand_ref
-
-
-def _bfs_state(g, level=2):
-    cm, rm = cheap_matching_jax(g)
-    nc = g.nc
-    cmj = jnp.concatenate([jnp.asarray(cm), jnp.array([-3], jnp.int32)])
-    rmj = jnp.concatenate([jnp.asarray(rm), jnp.array([-3], jnp.int32)])
-    bfs = jnp.where(cmj >= 0, jnp.int32(1), jnp.int32(2))
-    bfs = bfs.at[nc].set(jnp.int32(-(2 ** 30)))
-    root = jnp.where(cmj >= 0, jnp.int32(nc),
-                     jnp.arange(nc + 1, dtype=jnp.int32))
-    return bfs, root, rmj
-
-
-@pytest.mark.parametrize("nc,nr,deg,pad,blk", [
-    (256, 256, 3.0, 1024, 256),
-    (500, 700, 4.0, 4096, 512),
-    (1000, 1000, 6.0, 8192, 1024),
-    (64, 64, 2.0, 128, 128),
-    (777, 333, 5.0, 4096, 4096),
-])
-def test_frontier_expand_matches_ref(nc, nr, deg, pad, blk):
-    g = random_bipartite(nc, nr, deg, seed=nc + nr, pad_to=pad)
-    bfs, root, rmj = _bfs_state(g)
-    ecol, cadj = jnp.asarray(g.ecol), jnp.asarray(g.cadj)
-    for rt in (root, None):
-        out = frontier_expand(ecol, cadj, bfs, rt, rmj, 2, block_edges=blk)
-        ref = frontier_expand_ref(ecol, cadj, bfs, rt, rmj, jnp.int32(2))
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_frontier_expand_powerlaw_and_deeper_level():
-    g = scaled_free(512, 512, 6.0, seed=3, pad_to=8192)
-    bfs, root, rmj = _bfs_state(g)
-    # advance one level manually via the ref to get a deeper frontier
-    from repro.core.matcher import _expand_level
-    bfs2, root2, pred, rm2, ins, aug = _expand_level(
-        jnp.asarray(g.ecol), jnp.asarray(g.cadj), bfs, root,
-        jnp.full(g.nr + 1, jnp.int32(g.nc)), rmj, jnp.int32(2),
-        wr=True, wr_exact=False, use_pallas=False, block_edges=512)
-    out = frontier_expand(jnp.asarray(g.ecol), jnp.asarray(g.cadj), bfs2,
-                          root2, rm2, 3, block_edges=512)
-    ref = frontier_expand_ref(jnp.asarray(g.ecol), jnp.asarray(g.cadj), bfs2,
-                              root2, rm2, jnp.int32(3))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 @pytest.mark.parametrize("B,S,H,KV,hd,causal,bq,bk", [

@@ -169,7 +169,7 @@ def test_stacked_state_shapes(graph):
 
 
 # ---------------------------------------------------------------------------
-# bring-up guards: meshes, compiled Pallas, the persistent cache, the checker
+# bring-up guards: meshes, the persistent cache, the checker
 # ---------------------------------------------------------------------------
 def test_sharded_matcher_accepts_the_default_make_mesh(g):
     """jax.make_mesh types its axes Explicit; the matcher works on an Auto
@@ -191,24 +191,6 @@ def test_sharded_matcher_accepts_the_default_make_mesh(g):
         == {AxisType.Auto}
     state = ShardedMatcher(mesh, warm_start="cheap").run(placed)
     assert validate_matching(g, *state.to_host()) == maximum_cardinality(g)
-
-
-def test_compiled_pallas_is_refused_when_the_matcher_is_built(monkeypatch):
-    import re
-
-    from repro.kernels.frontier_expand.frontier_expand import MOSAIC_REJECTION
-    from repro.matching import SOLVE_PATHS, PallasUnsupportedError
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    pallas = [p for p in SOLVE_PATHS.values() if p.overrides.get("use_pallas")]
-    assert pallas
-    for path in pallas:
-        with pytest.raises(PallasUnsupportedError,
-                           match=re.escape(MOSAIC_REJECTION)):
-            path.matcher()
-    # the XLA sweep builds compiled; an explicitly interpreted kernel builds
-    assert Matcher(MatcherConfig()).config.pallas_interpret is False
-    assert Matcher(MatcherConfig(use_pallas=True,
-                                 pallas_interpret=True)).config.use_pallas
 
 
 def test_persistent_compile_cache_location(monkeypatch, tmp_path):

@@ -17,7 +17,7 @@ from repro.serving import (Bucketizer, MatchingService, MicroBatcher,
                            OversizeGraphError, SizeBucket, batch_bucket,
                            batch_ladder, synthetic_bucket_graph)
 
-CFG = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+CFG = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 BUCKET = SizeBucket(256, 256, 2048)
 
 
@@ -217,15 +217,14 @@ def test_warmup_makes_first_dispatch_compile_free():
 
 
 @pytest.mark.parametrize("kernel_cfg", [
-    dataclasses.replace(CFG, use_pallas=True),
+    CFG,
     dataclasses.replace(CFG, dirop=True),
-    dataclasses.replace(CFG, dirop=True, use_pallas=True),
-], ids=["pallas_fused", "dirop", "dirop_pallas"])
+], ids=["jnp", "dirop"])
 def test_warmup_zero_miss_across_kernel_paths(kernel_cfg):
-    """Serving x kernel paths: a service running the Pallas-fused or
-    direction-optimizing configs still gets a compile-free first dispatch
-    after warmup — the warmup grid must cover the new config axes,
-    including the CSC-mirrored graph shape dirop admissions carry."""
+    """Serving x kernel paths: a service running the push sweep or the
+    direction-optimizing config gets a compile-free first dispatch after
+    warmup — the warmup grid must cover the config axes, including the
+    CSC-mirrored graph shape dirop admissions carry."""
     compile_cache_clear()
     g = random_bipartite(200, 180, 3.0, seed=1)
     with MatchingService(bucketizer=Bucketizer((BUCKET,)), config=kernel_cfg,
@@ -242,22 +241,6 @@ def test_warmup_zero_miss_across_kernel_paths(kernel_cfg):
     assert compile_cache_info()["misses"] == misses0
     assert snap["compile_misses"] == 0 and snap["compile_hits"] >= 1
     assert res.cardinality == direct_cardinality(g)
-
-
-def test_service_rejects_adaptive_frontier_synchronously():
-    """run_many can never serve adaptive_frontier; the service must say so
-    in the caller's thread, not via an async failure on the flush thread."""
-    g = random_bipartite(128, 128, 3.0, seed=21)
-    acfg = dataclasses.replace(CFG, adaptive_frontier=True)
-    with pytest.raises(ValueError, match="dirop"):
-        MatchingService(bucketizer=Bucketizer((BUCKET,)), config=acfg)
-    with MatchingService(bucketizer=Bucketizer((BUCKET,)), config=CFG,
-                         warm_start="cheap", max_batch=4,
-                         max_delay_ms=5.0) as svc:
-        with pytest.raises(ValueError, match="dirop"):
-            svc.submit(g, config=acfg)
-        res = svc.submit(g).result(timeout=300)      # service still serves
-        assert res.cardinality == direct_cardinality(g)
 
 
 def test_dirop_admission_attaches_csc_mirror():

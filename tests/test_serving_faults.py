@@ -36,7 +36,7 @@ from repro.serving import (Bucketizer, DeadlineExceededError, FaultInjector,
                            PoisonedGraphFault, QueueFullError,
                            ServiceClosedError, SheddedError, SizeBucket)
 
-CFG = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+CFG = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
 BUCKET = SizeBucket(256, 256, 2048)
 
 

@@ -65,7 +65,7 @@ def test_serve_generates():
 
 def test_matching_end_to_end_instance_suite():
     """The paper's workload: full tiny instance suite, original + RCP."""
-    best = MatcherConfig(algo="apfb", kernel="gpubfs_wr", schedule="ct")
+    best = MatcherConfig(algo="apfb", kernel="gpubfs_wr")
     for name, g in instance_sets("tiny").items():
         for tag, gg in (("orig", g), ("rcp", g.permuted(13))):
             opt = maximum_cardinality(gg)
@@ -104,17 +104,3 @@ def test_adamw_factored_close_to_full():
         outs[factored] = loss(p)
     assert float(outs[True]) < float(loss(params)) * 0.05
     assert float(outs[False]) < float(loss(params)) * 0.05
-
-
-def test_pallas_matcher_agrees_with_jnp_matcher():
-    """use_pallas=True must give identical matchings phase-for-phase."""
-    from repro.graphs import random_bipartite
-    g = random_bipartite(800, 800, 4.0, seed=5, pad_to=4096)
-    cm0, rm0 = cheap_matching_jax(g)
-    cfgj = MatcherConfig(algo="apfb", kernel="gpubfs_wr", use_pallas=False)
-    cfgp = MatcherConfig(algo="apfb", kernel="gpubfs_wr", use_pallas=True)
-    cmj, rmj, stj = maximum_matching(g, cfgj, cm0, rm0)
-    cmp_, rmp, stp = maximum_matching(g, cfgp, cm0, rm0)
-    np.testing.assert_array_equal(cmj, cmp_)
-    np.testing.assert_array_equal(rmj, rmp)
-    assert stj["phases"] == stp["phases"]

@@ -4,15 +4,14 @@ The programs the chip runs compile at real size: the single-chip
 ``Matcher.run`` programs at 2^20 vertices per side and 2^23 edges, one
 ``match_many`` program at a serving bucket, and the ``ShardedMatcher``
 program over the four chips of a v5e 2x2, whose edge arrays must be spread
-a quarter per chip.  The Pallas frontier kernels must still be rejected by
-the TPU compiler: that rejection is what ``MatcherConfig.canonical`` refuses
-compiled Pallas for, so when a kernel compiles here this file fails and the
-guard comes out.  The single-chip programs also pin the XLA sweep's shape:
-one gather per edge slot in each BFS level.
+a quarter per chip.  The single-chip programs, the direction-optimizing
+one among them, also pin the XLA sweep's shape: one gather per edge slot in
+each BFS level.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers import every file.
 """
+import dataclasses
 import re
 
 import jax
@@ -21,10 +20,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.kernels.frontier_expand import (frontier_expand,
-                                           frontier_expand_fused,
-                                           frontier_expand_pull)
-from repro.kernels.frontier_expand.frontier_expand import MOSAIC_REJECTION
 from repro.matching import DeviceCSR, Matcher, MatcherConfig, ShardedMatcher
 from repro.matching.state import empty_like_graph
 from repro.serving import ladder
@@ -64,12 +59,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _graph(nc, nr, nnz, sharding, edge_sharding=None, batch=()):
+def _graph(nc, nr, nnz, sharding, edge_sharding=None, batch=(), csc=False):
     def leaf(shape, sh=sharding):
         return jax.ShapeDtypeStruct(batch + shape, jnp.int32, sharding=sh)
     edges = edge_sharding or sharding
+    mirror = {}
+    if csc:
+        mirror = dict(rxadj=leaf((nr + 1,)), radj=leaf((nnz,), edges),
+                      erow=leaf((nnz,), edges), eperm=leaf((nnz,), edges))
     return DeviceCSR(cxadj=leaf((nc + 1,)), cadj=leaf((nnz,), edges),
-                     ecol=leaf((nnz,), edges), nnz=leaf(()), nc=nc, nr=nr)
+                     ecol=leaf((nnz,), edges), nnz=leaf(()), nc=nc, nr=nr,
+                     **mirror)
 
 
 def _edge_gathers(hlo: str, scope: str) -> int:
@@ -158,14 +158,16 @@ def _state(graph, sharding):
         shapes)
 
 
+@pytest.mark.parametrize("dirop", [False, True], ids=["push", "dirop"])
 @pytest.mark.parametrize("cfg", [
     MatcherConfig(),
     MatcherConfig(algo="apsb", kernel="gpubfs_wr", wr_exact=True),
 ], ids=lambda c: c.name)
-def test_matcher_run_compiles_for_v5e(one_chip, cfg):
-    g = _graph(N, N, NNZ, one_chip)
+def test_matcher_run_compiles_for_v5e(one_chip, cfg, dirop):
+    g = _graph(N, N, NNZ, one_chip, csc=dirop)
     st = _state(g, one_chip)
-    matcher = Matcher(cfg, warm_start="karp_sipser")
+    matcher = Matcher(dataclasses.replace(cfg, dirop=dirop),
+                      warm_start="karp_sipser")
     compiled = matcher.program(g).lower(g, st).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= EDGE_BYTES
@@ -175,6 +177,7 @@ def test_matcher_run_compiles_for_v5e(one_chip, cfg):
             + mem.output_size_in_bytes) < 1 << 30, mem
     # the XLA sweep reads one column-side value per edge slot per level;
     # the row side of the proposal predicate waits for the winner vector
+    # (dirop's compact pull gathers O(cap * dmax), not an edge array)
     assert _edge_gathers(compiled.as_text(), "bfs_level") == 1
 
 
@@ -266,26 +269,6 @@ def test_sharded_cell_merges_one_winner_vector_per_level(sharded_cell_hlo,
     one = (Matcher(MatcherConfig(), warm_start="karp_sipser").program(g1)
            .lower(g1, _state(g1, one_chip)).compile().as_text())
     assert "merge_shards" not in one
-
-
-@pytest.mark.parametrize("wr", [True, False], ids=["wr", "plain"])
-@pytest.mark.parametrize("kernel", [frontier_expand, frontier_expand_fused,
-                                    frontier_expand_pull],
-                         ids=lambda k: k.__name__)
-def test_pallas_kernels_still_rejected_by_the_v5e_compiler(one_chip, kernel,
-                                                           wr):
-    n, nnz = 1 << 16, 1 << 19
-
-    def leaf(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    state = leaf((n + 1,))
-    sweep = jax.jit(lambda e, c, b, r, m, lvl: kernel(
-        e, c, b, r, m, lvl, block_edges=4096, interpret=False))
-    reason = MOSAIC_REJECTION.split(": ", 1)[1]
-    with pytest.raises(NotImplementedError, match=re.escape(reason)):
-        sweep.lower(leaf((nnz,)), leaf((nnz,)), state,
-                    state if wr else None, state, leaf(())).compile()
 
 
 def test_sharded_warm_start_moves_no_edge_array(sharded_cell_hlo):
